@@ -129,6 +129,9 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (fig4_conv2d, fig5_precision_sweep,
                             roofline_table, serve_microbench,
                             table2_kernel_report)

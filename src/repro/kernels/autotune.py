@@ -71,8 +71,8 @@ ENV_CACHE = "REPRO_AUTOTUNE_CACHE"
 
 #: Candidate grids (bounded by construction; the budget filter shrinks them
 #: further per shape).
-MATMUL_BLOCK_M = (16, 32, 64, 128, 256)
-MATMUL_BLOCK_N = (32, 64, 128, 256)
+MATMUL_BLOCK_M = (8, 16, 32, 64, 128, 256)
+MATMUL_BLOCK_N = (128, 256)
 MATMUL_CHUNKS = (1, 2, 4, 8, 16)
 CONV_BLOCK_CO = (4, 8, 16, 32)
 ATTN_CHUNKS = (32, 64, 128, 256, 512)
@@ -336,8 +336,8 @@ def matmul_candidates(m: int, kp: int, n: int, spec: PackSpec,
     for bm in _pow2_cap(MATMUL_BLOCK_M, m):
         for bn in _pow2_cap(MATMUL_BLOCK_N, n):
             for ch in MATMUL_CHUNKS:
-                if ch * spec.k_tile > 2 * kp:
-                    break
+                if (ch - 1) * plan_lib.MATMUL_LANES >= kp:
+                    break   # K block past the packed K: padding only
                 if plan_lib.matmul_working_set(bm, bn, ch, spec) <= budget:
                     cands.append((bm, bn, ch))
     return _bound(cands, limit)

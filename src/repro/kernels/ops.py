@@ -20,6 +20,8 @@ Both backends are bit-exact against kernels/ref.py oracles; tests enforce it.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -52,8 +54,39 @@ def packed_matmul(a_packed, w_packed, spec: PackSpec, *,
         plan = plan_lib.plan_packed_matmul(
             a2.shape[0], a2.shape[1], w_packed.shape[-1], spec,
             backend=backend, weight_store=weight_store, k_full=k_full)
-    out = plan_lib.dispatch(plan, a2, w_packed)
+    out = plan_lib.dispatch_on_mesh(
+        plan, (a2, w_packed), functools.partial(_column_layout, plan, a2,
+                                                w_packed))
     return out.reshape(*lead, w_packed.shape[-1])
+
+
+def _rows_layout(mesh, x2):
+    """Activation rows [M, K] on the serving mesh: split over its data
+    axes where they divide M, as ``sharding.constrain(x, 'dp', ...)``
+    lays them out, else whole on every device.  Returns (spec, rows one
+    device holds)."""
+    from repro.parallel.sharding import _axis_size, spec_on_mesh
+    spec = spec_on_mesh(mesh, x2.shape, "dp", None)
+    return spec, x2.shape[0] // _axis_size(mesh, spec[0])
+
+
+def _column_layout(plan: KernelPlan, a2, w, mesh):
+    """Per-device layout of the packed matmul (plan.dispatch_on_mesh): the
+    activation rows against the weight columns as the serving ShardPlan
+    stores them — split over 'model', or whole on every device where
+    'model' does not divide N — so each device reads only the weights it
+    holds, and the output keeps both layouts."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.parallel.sharding import _axis_size, spec_on_mesh
+    rows, m = _rows_layout(mesh, a2)
+    cols = spec_on_mesh(mesh, w.shape, None, "model")
+    n = w.shape[-1] // _axis_size(mesh, cols[1])
+    local = plan if (m, n) == (a2.shape[0], w.shape[-1]) else \
+        plan_lib.plan_packed_matmul(
+            m, a2.shape[1], n, plan.spec, backend=plan.backend,
+            weight_store=plan.weight_store, k_full=plan.k_full)
+    return (rows, cols), P(rows[0], cols[1]), local
 
 
 def _dense_to_lanes(words, spec: PackSpec, k_full: int):
@@ -134,9 +167,21 @@ def quantize_pack(x, scale, zero_point, spec: PackSpec, *,
     if plan is None:
         plan = plan_lib.plan_quantize_pack(x2.shape[0], x2.shape[1], spec,
                                            backend=backend)
-    packed, rs = plan_lib.dispatch(plan, x2, scale, zero_point)
+    packed, rs = plan_lib.dispatch_on_mesh(
+        plan, (x2, jnp.asarray(scale), jnp.asarray(zero_point)),
+        functools.partial(_pack_layout, plan, x2))
     kp = packed.shape[-1]
     return packed.reshape(*lead, kp), rs.reshape(*lead, 1)
+
+
+def _pack_layout(plan: KernelPlan, x2, mesh):
+    """Per-device layout of quantize_pack: each device packs the
+    activation rows it holds (scalars replicate)."""
+    from jax.sharding import PartitionSpec as P
+    rows, m = _rows_layout(mesh, x2)
+    local = plan if m == x2.shape[0] else plan_lib.plan_quantize_pack(
+        m, x2.shape[1], plan.spec, backend=plan.backend)
+    return (rows, P(), P()), (rows, rows), local
 
 
 @plan_lib.register_backend("quantize_pack", "pallas")

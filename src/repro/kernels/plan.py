@@ -48,6 +48,10 @@ VMEM_FRACTION = 0.5
 
 _CONV_BLOCK_H_CANDIDATES = (256, 128, 64, 32, 16, 8, 4, 2, 1)
 
+#: Lane grain of ulppack_matmul's N and K blocks (the TPU vector lane
+#: width); K blocks are ``chunks`` of it.
+MATMUL_LANES = 128
+
 
 def default_interpret() -> bool:
     """Pallas kernels run interpreted off-TPU (CPU validation mode).
@@ -64,7 +68,9 @@ class KernelPlan:
     """Frozen per-layer execution plan; see module docstring.
 
     Tile fields are populated per-op (``None`` where not applicable):
-      packed_matmul / int_matmul : block_m, block_n, chunks / block_k
+      packed_matmul              : block_m, block_n, chunks (K block =
+                                   chunks x 128 packed lanes)
+      int_matmul                 : block_m, block_n, block_k
       packed_conv2d              : block_h, block_co
       quantize_pack              : block_m, block_k
     """
@@ -158,6 +164,29 @@ def dispatch(plan: KernelPlan, *args, **kwargs):
     return get_backend(plan.op, plan.backend)(plan, *args, **kwargs)
 
 
+def dispatch_on_mesh(plan: KernelPlan, args: tuple, layout, **kwargs):
+    """:func:`dispatch` under the serving mesh, the one place that decides
+    how a kernel meets a multi-device mesh.
+
+    GSPMD cannot partition a Mosaic custom call: left to it, every operand
+    of a Pallas kernel is gathered whole onto each device (the packed
+    weights included).  So while the serving layer has a multi-device mesh
+    active (parallel/sharding.activation_mesh), a Pallas plan runs once per
+    device in ``jax.shard_map``.  ``layout(mesh)`` returns ``(in_specs,
+    out_specs, local_plan)``: the operands' stored layout on the mesh
+    (the serving ShardPlan's rules, so no operand is resharded) and the
+    plan for one device's shard.  One device, or the 'xla' backend (which
+    GSPMD partitions), is plain :func:`dispatch`."""
+    from repro.parallel.sharding import kernel_mesh
+    mesh = kernel_mesh() if plan.backend == "pallas" else None
+    if mesh is None:
+        return dispatch(plan, *args, **kwargs)
+    in_specs, out_specs, local = layout(mesh)
+    return jax.shard_map(
+        lambda *a: dispatch(local, *a, **kwargs), mesh=mesh,
+        in_specs=in_specs, out_specs=out_specs, check_vma=False)(*args)
+
+
 def resolve_backend(backend: str = "auto") -> str:
     if backend == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "xla"
@@ -176,11 +205,19 @@ def _lane_bytes(spec: PackSpec) -> int:
 
 def matmul_working_set(bm: int, bn: int, chunks: int,
                        spec: PackSpec) -> int:
-    """ulppack_matmul VMEM accounting: (bm*bk + bk*bn) lanes +
-    (chunks+1)*bm*bn s32 accumulator/output tiles."""
-    bk = chunks * spec.k_tile
-    return (bm * bk + bk * bn) * _lane_bytes(spec) + \
-        (chunks + 1) * bm * bn * 4
+    """ulppack_matmul VMEM accounting for a (bm, bn, bk = chunks * 128)
+    step: double-buffered packed lane blocks, their int32 and int8
+    unpacked planes, and the s32 accumulator plus double-buffered output
+    tile."""
+    bk = chunks * MATMUL_LANES
+    return (bm * bk + bk * bn) * (2 * _lane_bytes(spec) + 4 + 1) + \
+        3 * bm * bn * 4
+
+
+def matmul_tiles_ok(bm: int, bn: int, chunks: int) -> bool:
+    """The TPU compiler's block rule for ulppack_matmul: M blocks in
+    multiples of 8 rows, N and K blocks in multiples of 128 lanes."""
+    return bm % 8 == 0 and bn % MATMUL_LANES == 0 and chunks >= 1
 
 
 def conv2d_working_set(block_h: int, block_co: int, *, fh: int, fw: int,
@@ -232,12 +269,14 @@ def plan_packed_matmul(m: int, kp: int, n: int, spec: PackSpec, *,
                        use_tuning_cache: bool = True) -> KernelPlan:
     """Plan a packed-lane matmul [m, kp] x [kp, n].
 
-    The autotune cache (kernels/autotune.py) is consulted first: a hit whose
-    tiles still fit the VMEM budget becomes the plan (``source='tuned'``).
-    On miss, tile choice mirrors ulppack_matmul's VMEM accounting: working
-    set ~= (bm*bk + bk*bn) lanes + (chunks+1)*bm*bn s32.  Defaults (128,
-    128, chunks=8) are kept when they fit; otherwise chunks shrinks first
-    (it only amortizes grid overhead), then bn, then bm.
+    The autotune cache (kernels/autotune.py) is consulted first: a hit
+    whose tiles are TPU-aligned and still fit the VMEM budget becomes the
+    plan (``source='tuned'``).  On miss: ``block_m`` covers the rows up to
+    128 (a multiple of 8), ``block_n`` is 128 lanes, and the K block is the
+    largest ``chunks`` <= 16 (128-lane units) that divides the packed K, so
+    the weight operand is never re-padded per call.  Over budget, chunks
+    shrink first, then bm.  Every emitted block is a multiple of (8, 128)
+    (:func:`matmul_tiles_ok`).
     """
     spec.validate()   # beyond-bound layouts are rejected here, not in-kernel
     backend = resolve_backend(backend)
@@ -245,15 +284,20 @@ def plan_packed_matmul(m: int, kp: int, n: int, spec: PackSpec, *,
         k_full = kp * spec.n_pack
     budget = vmem_budget or int(hw.VMEM_PER_CORE * VMEM_FRACTION)
 
+    def working_set(bm, bn, chunks):
+        return matmul_working_set(bm, bn, chunks, spec)
+
+    def tuned_ws(e):
+        bm, bn, ch = int(e["block_m"]), int(e["block_n"]), int(e["chunks"])
+        return working_set(bm, bn, ch) if matmul_tiles_ok(bm, bn, ch) \
+            else None
+
     if use_tuning_cache:
         from repro.kernels import autotune
         entry = _tuned_entry(
             autotune.matmul_key(m, kp, n, spec, backend=backend,
                                 weight_store=weight_store),
-            budget,
-            lambda e: matmul_working_set(int(e["block_m"]),
-                                         int(e["block_n"]),
-                                         int(e["chunks"]), spec))
+            budget, tuned_ws)
         if entry is not None:
             bm, bn, chunks = (int(entry["block_m"]), int(entry["block_n"]),
                               int(entry["chunks"]))
@@ -261,19 +305,17 @@ def plan_packed_matmul(m: int, kp: int, n: int, spec: PackSpec, *,
                 op="packed_matmul", backend=backend, spec=spec,
                 interpret=default_interpret(), weight_store=weight_store,
                 k_full=k_full, block_m=bm, block_n=bn, chunks=chunks,
-                vmem_bytes=matmul_working_set(bm, bn, chunks, spec),
+                vmem_bytes=working_set(bm, bn, chunks),
                 source="tuned")
 
-    def working_set(bm, bn, chunks):
-        return matmul_working_set(bm, bn, chunks, spec)
-
-    bm, bn, chunks = 128, 128, 8
+    bm = min(128, -(-m // 8) * 8)
+    bn = MATMUL_LANES
+    k_blocks = -(-kp // MATMUL_LANES)
+    chunks = max(c for c in range(1, 17) if k_blocks % c == 0)
     while chunks > 1 and working_set(bm, bn, chunks) > budget:
-        chunks //= 2
-    while bn > 8 and working_set(bm, bn, chunks) > budget:
-        bn //= 2
+        chunks = max(c for c in range(1, chunks) if k_blocks % c == 0)
     while bm > 8 and working_set(bm, bn, chunks) > budget:
-        bm //= 2
+        bm = max(8, bm // 2 // 8 * 8)
     return KernelPlan(
         op="packed_matmul", backend=backend, spec=spec,
         interpret=default_interpret(), weight_store=weight_store,
@@ -360,16 +402,18 @@ def plan_quantize_pack(m: int, k: int, spec: PackSpec, *,
     """Plan the fused runtime quantize+pack over [m, k] activations."""
     backend = resolve_backend(backend)
     budget = vmem_budget or int(hw.VMEM_PER_CORE * VMEM_FRACTION)
-    bm = 256
+    bm = min(256, -(-m // 8) * 8)
     # cap the K tile at the (n_pack-rounded) activation width: a 512 default
     # on a narrow decode layer would quantize mostly padding
     k_rounded = max(spec.n_pack, -(-k // spec.n_pack) * spec.n_pack)
     bk = min(512, k_rounded)
 
     def working_set(bm, bk):
-        # f32 in + s32 lattice + packed lanes + row-sum scratch
-        return bm * bk * (4 + 4) + bm * (bk // spec.n_pack) * \
-            _lane_bytes(spec) + bm * 4
+        # double-buffered f32 in + s32/s8 lattice + the int8 selection
+        # matrix + s32 fields + double-buffered packed lanes + row sums
+        kp = bk // spec.n_pack
+        return bm * bk * (2 * 4 + 4 + 1) + bk * kp * 5 + \
+            bm * kp * (4 + 2 * _lane_bytes(spec)) + bm * 4
 
     while bm > 8 and working_set(bm, bk) > budget:
         bm //= 2
@@ -409,9 +453,10 @@ def plan_attention_decode(b: int, skv: int, h: int, kvh: int, hd: int,
     Tile fields: ``block_k`` = KV token rows per online-softmax group,
     ``chunks`` = block-table pages walked per group (paged only; always
     ``block_k // page_size``).  The autotune cache is consulted first
-    (kernels/autotune.tune_attention_decode); the heuristic picks the
-    largest power-of-two group <= 512 rows that fits the VMEM budget —
-    groups only amortize the combine epilogue, so smaller is safe.
+    (kernels/autotune.tune_attention_decode); the heuristic takes 8
+    pages' worth of rows (128 for the default 16-row page, contiguous
+    caches included), halving while over the VMEM budget — groups only
+    amortize the combine epilogue, so smaller is safe.
     """
     backend = resolve_backend(backend)
     groups = max(1, h // kvh)
@@ -443,7 +488,10 @@ def plan_attention_decode(b: int, skv: int, h: int, kvh: int, hd: int,
                                                         groups),
                 source="tuned")
 
-    bk = 512 if page_size is None else 8 * page_size
+    # one default group length for both layouts (8 pages of the default
+    # 16-row page): the contiguous and the paged read then combine the
+    # same token groups, so they agree bit for bit
+    bk = 8 * (page_size or 16)
     bk, chunks = clamp(bk)
     while bk > (page_size or 1) and \
             attention_decode_working_set(bk, kvh, hd, groups) > budget:

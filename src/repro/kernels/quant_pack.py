@@ -27,18 +27,29 @@ def _kernel(x_ref, s_ref, z_ref, packed_ref, rs_ref, rs_acc,
     def _init():
         rs_acc[...] = jnp.zeros_like(rs_acc)
 
-    x = x_ref[...].astype(jnp.float32)
     scale = s_ref[0, 0]
     zp = z_ref[0, 0]
     qmax = (1 << spec.a_bits) - 1
+    x = x_ref[...].astype(jnp.float32)
     q = jnp.clip(jnp.round(x / scale) + zp, 0, qmax).astype(jnp.int32)
-    bm, bk = q.shape
-    qr = q.reshape(bm, bk // spec.n_pack, spec.n_pack)
-    packed = jnp.zeros(qr.shape[:2], jnp.int32)
-    for j in range(spec.n_pack):
-        packed = packed + (qr[..., j] << (spec.shift * j))
-    packed_ref[...] = packed.astype(spec.lane_dtype)
     rs_acc[...] += jnp.sum(q, axis=1, keepdims=True)
+    # De-interleave on the MXU: field j of lane i is column n_pack*i + j,
+    # picked out by an exact 0/1 selection matmul (Mosaic lowers neither
+    # the [bm, kp, n_pack] reshape nor lane-strided reads).  8-bit fields
+    # are recentred into int8 and shifted back after the selection.
+    bm, bk = q.shape
+    kp = bk // spec.n_pack
+    off = 128 if spec.a_bits == 8 else 0
+    q8 = (q - off).astype(jnp.int8)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (bk, kp), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bk, kp), 1)
+    packed = jnp.zeros((bm, kp), jnp.int32)
+    for j in range(spec.n_pack):
+        sel = (rows == cols * spec.n_pack + j).astype(jnp.int8)
+        field = jax.lax.dot_general(q8, sel, (((1,), (0,)), ((), ())),
+                                    preferred_element_type=jnp.int32)
+        packed = packed + ((field + off) << (spec.shift * j))
+    packed_ref[...] = packed.astype(spec.lane_dtype)
 
     @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
     def _done():

@@ -25,8 +25,8 @@ restructures decode attention as flash-decoding (DESIGN.md §20):
 Two registered backends for the ``attention_decode`` op:
 
   'xla'    — the algorithm above in plain jnp (python-unrolled group loop).
-             This is the deployed CPU path and the only GSPMD-partitionable
-             one, so kv-head-sharded serving (``kv_shard_axis``) pins it.
+             This is the deployed CPU path, the chip's path for windows of
+             more than one query row, and GSPMD partitions it under a mesh.
   'pallas' — the real kernel: grid (batch, kv-split), online-softmax carry
              in VMEM scratch, shift-mask word unpack in-kernel, and — paged
              — a scalar-prefetched block table whose entries ARE the
@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.reduce import ordered_sum
 from repro.kernels import plan as plan_lib
 
 NEG_INF = -1e30
@@ -101,7 +102,7 @@ def _prep_q(q, kvh):
     b, c, h, hd = q.shape
     qg = (q.astype(jnp.float32) * hd ** -0.5).reshape(b, c, kvh,
                                                       h // kvh, hd)
-    return qg, jnp.sum(qg, axis=-1)
+    return qg, ordered_sum(qg)
 
 
 def _combine(carry, s, ok, u_v, ssv, zp):
@@ -109,18 +110,20 @@ def _combine(carry, s, ok, u_v, ssv, zp):
     [B, C, KVH, G, L] and values ``u_v`` [B, L, KVH, hd] into the running
     (max, sum, acc) carry.  ``ssv`` is the group's value-scale plane
     broadcast like ``s`` (None for float caches), ``zp`` the lattice
-    midpoint (0 for symmetric/float storage)."""
+    midpoint (0 for symmetric/float storage).  Sums run in a fixed order
+    (core/reduce.py), so a query row scores the same in a one-row decode
+    step, a 32-row prefill window and an uncached forward."""
     m, l, acc = carry
     s = jnp.where(ok, s, NEG_INF)
     mn = jnp.maximum(m, jnp.max(s, axis=-1))
     corr = jnp.exp(m - mn)
     p = jnp.where(ok, jnp.exp(s - mn[..., None]), 0.0)
-    l2 = l * corr + jnp.sum(p, axis=-1)
+    l2 = l * corr + ordered_sum(p)
     pv = p if ssv is None else p * ssv
     av = jnp.einsum("bckgs,bskd->bckgd", pv, u_v,
                     preferred_element_type=jnp.float32)
     if zp:
-        av = av - (zp * jnp.sum(pv, axis=-1))[..., None]
+        av = av - (zp * ordered_sum(pv))[..., None]
     return mn, l2, acc * corr[..., None] + av
 
 
@@ -156,8 +159,8 @@ def _scale_broadcast(gsv):
 # ---------------------------------------------------------------------------
 
 @plan_lib.register_backend("attention_decode", "xla")
-def _attention_decode_xla(plan, q, cache, valid_len, qpos, *, kv_bits, hd,
-                          block_tables=None):
+def _attention_decode_xla(plan, q, cache, valid_len, qpos,
+                          block_tables=None, *, kv_bits, hd):
     """Python-unrolled group loop; each group guarded by a ``lax.cond`` on
     ``group_start < max(valid_len)`` so fully-dead groups cost one scalar
     compare instead of an unpack + two contractions."""
@@ -225,19 +228,46 @@ def _attention_decode_xla(plan, q, cache, valid_len, qpos, *, kv_bits, hd,
 
 # ---------------------------------------------------------------------------
 # 'pallas' backend — the real kernel (interpreted off-TPU)
+#
+# Mosaic lowers plain 2-D matmuls, not the [span, KVH, hd] head-batched
+# contractions or the in-register word reshapes of the 'xla' path, so the
+# kernel works on a lane-flat cache row: one token row is its KVH kv-head
+# words side by side, [KVH * hdw] lanes (a free reshape of the stored
+# cache).  Field j of every word is one shift-mask plane u_j [span, KVH*hdw]
+# holding dims w*per + j of each head.  The query side is laid out to match
+# in the wrapper: per field j, a block-diagonal [H, KVH*hdw] matrix whose
+# row (kvh, g) holds that query head's dims w*per + j in kv head kvh's
+# lane block and zeros elsewhere.  Then
+#
+#     scores [H, span] = sum_j  qbd_j @ u_j^T
+#     acc_j  [H, KVH*hdw] += p @ v_j
+#
+# are plain 2-D MXU matmuls, and the wrapper reads each head's output off
+# the diagonal block of acc.  Per-(pos, kv-head) scale planes expand to
+# [H, span] rows through a one-hot [H, KVH] matmul.  Unpacked caches (bf16,
+# int8) are the per = 1 case.
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(qg_ref, qs_ref, vl_ref, qp_ref, k_ref, v_ref, sk_ref,
-                   sv_ref, o_ref, m_ref, l_ref, acc_ref, *, kv_bits, hd,
-                   zp, span):
-    """Grid (B, n_splits): one batch row x one KV group per program.
+_HI = jax.lax.Precision.HIGHEST
 
-    Carry lives in VMEM scratch across the split sweep (same discipline as
-    ulppack_matmul's accumulator); split j covers token rows
-    ``j*span .. j*span+span`` of the row's logical view — for the paged
-    variant the group's pool block was already selected by the
-    block-table index_map, so position arithmetic is identical."""
-    j = pl.program_id(1)
+
+def _decode_kernel(*refs, kv_bits, per, zp, span, paged, quantized):
+    """Grid (B, n_groups): one batch row x one KV group per program; the
+    online-softmax carry lives in VMEM scratch across the group sweep.
+    Group j covers logical token rows j*span .. j*span+span (for the paged
+    variant the index_map already picked the group's pool page)."""
+    if paged:
+        _, vl_ref, qp_ref, *refs = refs
+    else:
+        vl_ref, qp_ref, *refs = refs
+    if quantized:
+        (qbd_ref, qs_ref, e_ref, k_ref, v_ref, sk_ref, sv_ref, o_ref,
+         m_ref, l_ref, acc_ref) = refs
+    else:
+        (qbd_ref, qs_ref, e_ref, k_ref, v_ref, o_ref,
+         m_ref, l_ref, acc_ref) = refs
+    i, j = pl.program_id(0), pl.program_id(1)
+    valid_len, qpos = vl_ref[i], qp_ref[i]
 
     @pl.when(j == 0)
     def _init():
@@ -245,44 +275,56 @@ def _decode_kernel(qg_ref, qs_ref, vl_ref, qp_ref, k_ref, v_ref, sk_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qg = qg_ref[0]                                  # [KVH, G, hd] f32
-    if kv_bits in (4, 2):
-        u_k = _unpack_group(k_ref[0], kv_bits, hd)  # [span, KVH, hd]
-        u_v = _unpack_group(v_ref[0], kv_bits, hd)
-    else:
-        u_k = k_ref[0].astype(jnp.float32)
-        u_v = v_ref[0].astype(jnp.float32)
-    # batched over KVH: [KVH, G, hd] x [span, KVH, hd] -> [KVH, G, span]
-    s = jax.lax.dot_general(qg, u_k, (((2,), (2,)), ((0,), (1,))),
-                            preferred_element_type=jnp.float32)
-    if sk_ref is not None:
-        ssk = sk_ref[0].astype(jnp.float32).T[:, None, :]   # [KVH, 1, span]
-        if zp:
-            s = ssk * (s - zp * qs_ref[0][..., None])
-        else:
-            s = ssk * s
-    pos = j * span + jnp.arange(span, dtype=jnp.int32)
-    ok = ((pos < vl_ref[0, 0]) & (pos <= qp_ref[0, 0]))[None, None, :]
-    s = jnp.where(ok, s, NEG_INF)
-    m = m_ref[...]
-    mn = jnp.maximum(m, jnp.max(s, axis=-1))
-    corr = jnp.exp(m - mn)
-    p = jnp.where(ok, jnp.exp(s - mn[..., None]), 0.0)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-    if sv_ref is not None:
-        p = p * sv_ref[0].astype(jnp.float32).T[:, None, :]
-    # [KVH, G, span] x [span, KVH, hd] -> [KVH, G, hd]
-    av = jax.lax.dot_general(p, u_v, (((2,), (0,)), ((0,), (1,))),
-                             preferred_element_type=jnp.float32)
-    if zp:
-        av = av - (zp * jnp.sum(p, axis=-1))[..., None]
-    acc_ref[...] = acc_ref[...] * corr[..., None] + av
-    m_ref[...] = mn
+    def planes(ref):
+        x = ref[0]
+        if per == 1:
+            return [x.astype(jnp.float32)]
+        mask = (1 << kv_bits) - 1
+        return [((x >> (kv_bits * f)) & mask).astype(jnp.float32)
+                for f in range(per)]
+
+    def expand(scale_ref):
+        # [span, KVH] scale plane -> [H, span] rows via the one-hot E
+        return jax.lax.dot_general(
+            e_ref[...], scale_ref[0].astype(jnp.float32),
+            (((1,), (1,)), ((), ())), precision=_HI,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j * span < valid_len)          # groups past every live row skip
+    def _group():
+        u_k = planes(k_ref)
+        s = None
+        for f in range(per):
+            t = jax.lax.dot_general(qbd_ref[0, f], u_k[f],
+                                    (((1,), (1,)), ((), ())), precision=_HI,
+                                    preferred_element_type=jnp.float32)
+            s = t if s is None else s + t                   # [H, span]
+        if quantized:
+            s = expand(sk_ref) * (s - zp * qs_ref[0] if zp else s)
+        pos = j * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+        ok = (pos < valid_len) & (pos <= qpos)
+        s = jnp.where(ok, s, NEG_INF)
+        m = m_ref[...]
+        mn = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m - mn)
+        p = jnp.where(ok, jnp.exp(s - mn), 0.0)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_ref[...] = mn
+        if quantized:
+            p = p * expand(sv_ref)
+        psum = jnp.sum(p, axis=1, keepdims=True)
+        for f, u in enumerate(planes(v_ref)):
+            av = jax.lax.dot_general(p, u, (((1,), (0,)), ((), ())),
+                                     precision=_HI,
+                                     preferred_element_type=jnp.float32)
+            if zp:
+                av = av - zp * psum
+            acc_ref[f] = acc_ref[f] * corr + av
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _done():
         ll = l_ref[...]
-        o_ref[0] = acc_ref[...] / jnp.where(ll == 0, 1.0, ll)[..., None]
+        o_ref[0] = acc_ref[...] / jnp.where(ll == 0, 1.0, ll)[None]
 
 
 def _pad_tokens(x, multiple):
@@ -294,128 +336,111 @@ def _pad_tokens(x, multiple):
     return jnp.pad(x, pad)
 
 
+def _block_diag_query(q, kvh, per, hdw):
+    """q [B, 1, H, hd] -> pre-scaled block-diagonal field planes
+    [B, per, H, KVH*hdw] (see the section comment) and the per-head
+    query sums [B, H, 1] the zero-point term needs."""
+    b, _, h, hd = q.shape
+    qg, qsum = _prep_q(q, kvh)
+    qg = qg[:, 0]                                      # [B, KVH, G, hd]
+    qf = jnp.pad(qg, ((0, 0),) * 3 + ((0, hdw * per - hd),))
+    qf = qf.reshape(b, kvh, h // kvh, hdw, per).transpose(0, 4, 1, 2, 3)
+    # elementwise with the identity (a dot would round q to bf16 on TPU)
+    eye = jnp.eye(kvh, dtype=jnp.float32)[:, None, :, None]
+    qbd = qf[:, :, :, :, None, :] * eye        # [B, per, KVH, G, KVH, hdw]
+    return (qbd.reshape(b, per, h, kvh * hdw),
+            qsum[:, 0].reshape(b, h, 1))
+
+
 @plan_lib.register_backend("attention_decode", "pallas")
-def _attention_decode_pallas(plan, q, cache, valid_len, qpos, *, kv_bits,
-                             hd, block_tables=None):
+def _attention_decode_pallas(plan, q, cache, valid_len, qpos,
+                             block_tables=None, *, kv_bits, hd):
     """Pallas flash-decoding kernel; sq == 1 decode only (the dispatcher
     routes wider windows to the 'xla' backend).
 
     Contiguous: grid (B, ceil(Sk / block_k)), token-sliced BlockSpecs.
-    Paged: grid (B, n_pages) under ``PrefetchScalarGridSpec`` — the
-    scalar-prefetched block table IS the pool index_map (``bt[i, j]``),
-    one page per grid step, so the kernel walks each row's page list
-    without ever materializing the gathered view."""
+    Paged: grid (B, n_pages) — the scalar-prefetched block table IS the
+    pool index_map (``bt[i, j]``), one page per grid step, so the kernel
+    walks each row's page list without materializing the gathered view.
+    ``valid_len``/``qpos`` ride in SMEM as scalar-prefetch operands."""
     b, c, h, _ = q.shape
     if c != 1:
         raise ValueError("pallas attention_decode handles sq == 1 only")
     kvh = cache["k"].shape[2]
     groups = h // kvh
-    zp = (1 << (kv_bits - 1)) if kv_bits in (4, 2) else 0
+    packed = kv_bits in (4, 2)
+    per = 32 // kv_bits if packed else 1
+    zp = (1 << (kv_bits - 1)) if packed else 0
     quantized = "k_scale" in cache
-    qg, qsum = _prep_q(q, kvh)
-    qg = qg[:, 0]                                   # [B, KVH, G, hd]
-    qsum = qsum[:, 0]
-    vl = valid_len.astype(jnp.int32).reshape(b, 1)
-    qp = qpos[:, 0].astype(jnp.int32).reshape(b, 1)
-    word_dim = cache["k"].shape[-1]
-    scratch = [pltpu.VMEM((kvh, groups), jnp.float32),
-               pltpu.VMEM((kvh, groups), jnp.float32),
-               pltpu.VMEM((kvh, groups, hd), jnp.float32)]
-    out_shape = jax.ShapeDtypeStruct((b, kvh, groups, hd), jnp.float32)
+    hdw = cache["k"].shape[-1]
+    width = kvh * hdw
+    qbd, qsum = _block_diag_query(q, kvh, per, hdw)
+    onehot = (jnp.arange(h)[:, None] // groups
+              == jnp.arange(kvh)[None, :]).astype(jnp.float32)
+    vl = valid_len.astype(jnp.int32).reshape(b)
+    qp = qpos[:, 0].astype(jnp.int32).reshape(b)
+    paged = block_tables is not None
 
-    if block_tables is not None:
-        page_rows = cache["k"].shape[1]
+    def flat(x):            # [N, rows, KVH, hdw] -> [N, rows, KVH*hdw]
+        return x.reshape(*x.shape[:2], -1)
+
+    if paged:
+        span = cache["k"].shape[1]
+        n_groups = block_tables.shape[1]
         bt = jnp.clip(block_tables.astype(jnp.int32), 0,
                       cache["k"].shape[0] - 1)
-        kern = functools.partial(_decode_kernel, kv_bits=kv_bits, hd=hd,
-                                 zp=zp, span=page_rows)
-        if not quantized:
-            kern = functools.partial(_no_scale_kernel, kern)
-        # scalar-prefetch operands are handed to the kernel as a leading
-        # ref; the index_maps already consumed the table, so drop it here
-        kern = functools.partial(_drop_prefetch_ref, kern)
-        # index_maps take (i, j, bt_ref): batch-row operands index by i,
-        # pool operands by the scalar-prefetched block table — the
-        # in-kernel block-table walk.
-        in_specs = [
-            pl.BlockSpec((1, kvh, groups, hd),
-                         lambda i, j, bt_: (i, 0, 0, 0)),
-            pl.BlockSpec((1, kvh, groups), lambda i, j, bt_: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, bt_: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j, bt_: (i, 0)),
-            pl.BlockSpec((1, page_rows, kvh, word_dim),
-                         lambda i, j, bt_: (bt_[i, j], 0, 0, 0)),
-            pl.BlockSpec((1, page_rows, kvh, word_dim),
-                         lambda i, j, bt_: (bt_[i, j], 0, 0, 0)),
-        ]
-        args = [qg, qsum, vl, qp, cache["k"], cache["v"]]
-        if quantized:
-            in_specs += [
-                pl.BlockSpec((1, page_rows, kvh),
-                             lambda i, j, bt_: (bt_[i, j], 0, 0)),
-                pl.BlockSpec((1, page_rows, kvh),
-                             lambda i, j, bt_: (bt_[i, j], 0, 0)),
-            ]
-            args += [cache["k_scale"], cache["v_scale"]]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, block_tables.shape[1]),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, kvh, groups, hd),
-                                   lambda i, j, bt_: (i, 0, 0, 0)),
-            scratch_shapes=scratch)
-        out = pl.pallas_call(kern, grid_spec=grid_spec,
-                             out_shape=out_shape,
-                             interpret=plan.interpret)(bt, *args)
-        return out.reshape(b, 1, h, hd).astype(q.dtype)
+        prefetch = (bt, vl, qp)
+        kv_map = lambda i, j, bt_, vl_, qp_: (bt_[i, j], 0, 0)
+        row_map = lambda i, j, *_: (i, 0, 0)
+        row4_map = lambda i, j, *_: (i, 0, 0, 0)
+        const_map = lambda i, j, *_: (0, 0)
+        ks, vs = flat(cache["k"]), flat(cache["v"])
+        scales = [cache["k_scale"], cache["v_scale"]] if quantized else []
+    else:
+        skv = cache["k"].shape[1]
+        span = min(max(1, plan.block_k or skv), skv)
+        if span < skv:
+            span = -(-span // 8) * 8
+        prefetch = (vl, qp)
+        kv_map = lambda i, j, vl_, qp_: (i, j, 0)
+        row_map = lambda i, j, *_: (i, 0, 0)
+        row4_map = lambda i, j, *_: (i, 0, 0, 0)
+        const_map = lambda i, j, *_: (0, 0)
+        ks = _pad_tokens(flat(cache["k"]), span)
+        vs = _pad_tokens(flat(cache["v"]), span)
+        scales = ([_pad_tokens(cache["k_scale"], span),
+                   _pad_tokens(cache["v_scale"], span)] if quantized
+                  else [])
+        n_groups = ks.shape[1] // span
 
-    skv = cache["k"].shape[1]
-    bk = min(max(1, plan.block_k or skv), skv)
-    kern = functools.partial(_decode_kernel, kv_bits=kv_bits, hd=hd, zp=zp,
-                             span=bk)
-    if not quantized:
-        kern = functools.partial(_no_scale_kernel, kern)
-    in_specs = [
-        pl.BlockSpec((1, kvh, groups, hd), lambda i, j: (i, 0, 0, 0)),
-        pl.BlockSpec((1, kvh, groups), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-        pl.BlockSpec((1, bk, kvh, word_dim), lambda i, j: (i, j, 0, 0)),
-        pl.BlockSpec((1, bk, kvh, word_dim), lambda i, j: (i, j, 0, 0)),
-    ]
-    ks = _pad_tokens(cache["k"], bk)
-    args = [qg, qsum, vl, qp, ks, _pad_tokens(cache["v"], bk)]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, bk, kvh), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, bk, kvh), lambda i, j: (i, j, 0)),
-        ]
-        args += [_pad_tokens(cache["k_scale"], bk),
-                 _pad_tokens(cache["v_scale"], bk)]
-    out = pl.pallas_call(
-        kern,
-        grid=(b, ks.shape[1] // bk),
+    in_specs = [pl.BlockSpec((1, per, h, width), row4_map),
+                pl.BlockSpec((1, h, 1), row_map),
+                pl.BlockSpec((h, kvh), const_map),
+                pl.BlockSpec((1, span, width), kv_map),
+                pl.BlockSpec((1, span, width), kv_map)]
+    in_specs += [pl.BlockSpec((1, span, kvh), kv_map) for _ in scales]
+    kern = functools.partial(_decode_kernel, kv_bits=kv_bits, per=per,
+                             zp=zp, span=span, paged=paged,
+                             quantized=quantized)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(b, n_groups),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, kvh, groups, hd),
-                               lambda i, j: (i, 0, 0, 0)),
-        out_shape=out_shape,
-        scratch_shapes=scratch,
+        out_specs=pl.BlockSpec((1, per, h, width), row4_map),
+        scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((per, h, width), jnp.float32)])
+    out = pl.pallas_call(
+        kern, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, per, h, width), jnp.float32),
         interpret=plan.interpret,
-    )(*args)
+    )(*prefetch, qbd, qsum, onehot, ks, vs, *scales)
+    # each head's output is the diagonal (own kv head) block of acc
+    out = out.reshape(b, per, kvh, groups, kvh, hdw)
+    eye = jnp.eye(kvh, dtype=jnp.float32)[:, None, :, None]
+    out = jnp.sum(out * eye, axis=4)           # [B, per, KVH, G, hdw]
+    out = out.transpose(0, 2, 3, 4, 1).reshape(b, h, hdw * per)[..., :hd]
     return out.reshape(b, 1, h, hd).astype(q.dtype)
-
-
-def _no_scale_kernel(kern, qg_ref, qs_ref, vl_ref, qp_ref, k_ref, v_ref,
-                     o_ref, m_ref, l_ref, acc_ref):
-    """Adapter for float (kv_bits 0/16) caches: no scale-plane operands."""
-    kern(qg_ref, qs_ref, vl_ref, qp_ref, k_ref, v_ref, None, None, o_ref,
-         m_ref, l_ref, acc_ref)
-
-
-def _drop_prefetch_ref(kern, bt_ref, *refs):
-    """Adapter for the paged variant: the scalar-prefetched block table
-    arrives as the kernel's leading ref but is only read by index_maps."""
-    kern(*refs)
 
 
 # ---------------------------------------------------------------------------
@@ -423,7 +448,8 @@ def _drop_prefetch_ref(kern, bt_ref, *refs):
 # ---------------------------------------------------------------------------
 
 def fused_decode_attention(q, cache, valid_len, qpos, *, kv_bits, hd,
-                           plan=None, block_tables=None, backend="auto"):
+                           plan=None, block_tables=None, backend="auto",
+                           shard_axis=None):
     """Flash-decoding attention over the stored (possibly packed) cache.
 
     q [B, C, H, hd]; ``cache`` the stored layout (init_kv_cache /
@@ -431,21 +457,44 @@ def fused_decode_attention(q, cache, valid_len, qpos, *, kv_bits, hd,
     (logical-view prefix); ``qpos`` [B, C] absolute query positions.
     ``plan`` defaults to :func:`plan_attention_decode` for the shape;
     the 'pallas' backend serves C == 1 only (wider verify windows route
-    to 'xla').  Returns [B, C, H, hd] in q.dtype.
+    to 'xla').  ``shard_axis`` names the mesh axis the serving cache's
+    kv heads shard over (DESIGN.md §15): the 'xla' backend is left to
+    GSPMD, the Pallas kernel runs once per head shard under shard_map.
+    Returns [B, C, H, hd] in q.dtype.
     """
     b, c, h, _ = q.shape
     kvh = cache["k"].shape[2]
-    if plan is None:
+    backend = plan.backend if plan is not None \
+        else plan_lib.resolve_backend(backend)
+    if backend == "pallas" and c != 1:
+        backend = "xla"
+
+    def plan_for(shards):
+        if plan is not None:
+            return dataclasses.replace(plan, backend=backend)
         page_size = cache["k"].shape[1] if block_tables is not None else None
         skv = (block_tables.shape[1] * cache["k"].shape[1]
                if block_tables is not None else cache["k"].shape[1])
-        plan = plan_lib.plan_attention_decode(
-            b, skv, h, kvh, hd, kv_bits, page_size=page_size,
-            backend=backend)
-    if plan.backend == "pallas" and c != 1:
-        plan = dataclasses.replace(plan, backend="xla")
-    return plan_lib.dispatch(plan, q, cache,
-                             jnp.asarray(valid_len, jnp.int32),
-                             jnp.asarray(qpos, jnp.int32),
-                             kv_bits=kv_bits, hd=hd,
-                             block_tables=block_tables)
+        return plan_lib.plan_attention_decode(
+            b, skv, h // shards, kvh // shards, hd, kv_bits,
+            page_size=page_size, backend=backend)
+
+    def layout(mesh):
+        # the cache's kv heads lie on ``shard_axis`` where it divides them
+        # (the serving ShardPlan's rule); queries and outputs follow them
+        from jax.sharding import PartitionSpec as P
+
+        from repro.parallel.sharding import _axis_size, spec_on_mesh
+        axis = spec_on_mesh(mesh, (kvh,), shard_axis)[0] \
+            if shard_axis is not None else None
+        heads = P(None, None, axis, None)
+        cache_spec = {k: P(None, None, axis, *([None] * (v.ndim - 3)))
+                      for k, v in cache.items()}
+        return ((heads, cache_spec, P(), P(),
+                 None if block_tables is None else P()), heads,
+                plan_for(_axis_size(mesh, axis)))
+
+    return plan_lib.dispatch_on_mesh(
+        plan_for(1), (q, cache, jnp.asarray(valid_len, jnp.int32),
+                      jnp.asarray(qpos, jnp.int32), block_tables),
+        layout, kv_bits=kv_bits, hd=hd)

@@ -1,15 +1,17 @@
 """Packed sub-byte conv2d Pallas kernel (paper §IV-B, Algorithm 1 on TPU).
 
-Output-stationary, channel-packed (ULPPACK P1 over the C axis), with the
-``vmacsr`` shift-extract fused after every packed MXU contraction.  The
-paper's ``vslidedown`` input reuse becomes VMEM-resident window slicing: each
+Output-stationary, channel-packed (ULPPACK P1 over the C axis).  As in
+ulppack_matmul, the packed channel lanes are unpacked in VMEM into int8
+field planes and every plane pair is one int8 x int8 -> int32 MXU
+contraction (the v5e MXU takes int8 operands only).  The paper's
+``vslidedown`` input reuse becomes VMEM-resident window slicing: each
 (fh, fw) kernel tap is a shifted view of the VMEM input tile — no im2col
 materialization in HBM, mirroring the paper's motivation for a dedicated conv
 algorithm (§III-A).
 
 Spatial tiling (DESIGN.md §10): grid ``(N, out_H/block_h, Co/block_co)``.
 Each grid step loads a halo-overlapped input tile of ``block_h + fh - 1`` rows
-(``pl.Unblocked`` indexing: consecutive h-tiles advance by ``block_h`` rows
+(``pl.Element`` indexing on H: consecutive h-tiles advance by ``block_h`` rows
 but read ``fh - 1`` shared halo rows), so VMEM use is bounded by the tile —
 not the image — and large-resolution inference stays feasible.  ``block_h``
 is chosen offline by kernels/plan.py against the VMEM budget.
@@ -35,6 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import PackSpec
 from repro.kernels import plan as plan_lib
+from repro.kernels.ulppack_matmul import packed_dot
 
 
 def expand_dense_taps(words: jax.Array, spec: PackSpec,
@@ -66,8 +69,6 @@ def _kernel(x_ref, w_ref, o_ref, *scratch, spec: PackSpec, fh: int, fw: int,
             block_h: int, out_w: int, weight_store: str, k_full: int | None):
     cp = x_ref.shape[-1]
     bco = w_ref.shape[-1]
-    kt = spec.k_tile
-    band = spec.shift * (spec.n_pack - 1)
     if weight_store == "dense":
         # the co-block is the OUTERMOST grid dim, so the expanded lanes in
         # scratch stay valid across the whole (N, h-tile) inner sweep —
@@ -86,13 +87,7 @@ def _kernel(x_ref, w_ref, o_ref, *scratch, spec: PackSpec, fh: int, fw: int,
             window = jax.lax.slice(
                 x, (ih, iw, 0), (ih + block_h, iw + out_w, cp))
             rows = window.reshape(block_h * out_w, cp)
-            for c0 in range(0, cp, kt):
-                c1 = min(c0 + kt, cp)
-                t = jax.lax.dot_general(
-                    rows[:, c0:c1], wt[ih, iw, c0:c1, :],
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32)
-                acc = acc + ((t >> band) & spec.field_mask)
+            acc = acc + packed_dot(rows, wt[ih, iw], spec)
     o_ref[...] = acc.reshape(1, block_h, out_w, bco)
 
 
@@ -149,9 +144,12 @@ def _tiled_conv_call(kernel, x, w, *, fh, fw, block_h, block_co, out_h,
         kernel,
         grid=(gco, n, n_bh),
         in_specs=[
-            pl.BlockSpec((1, block_h + fh - 1, wd, cdim),
-                         lambda j, i, hb, bh=block_h: (i, hb * bh, 0, 0),
-                         indexing_mode=pl.Unblocked()),
+            # element-indexed (Mosaic takes all dims as Elements or none):
+            # tile hb starts at row hb*bh and reads fh-1 halo rows shared
+            # with the next tile
+            pl.BlockSpec((pl.Element(1), pl.Element(block_h + fh - 1),
+                          pl.Element(wd), pl.Element(cdim)),
+                         lambda j, i, hb, bh=block_h: (i, hb * bh, 0, 0)),
             pl.BlockSpec((fh, fw, w.shape[2], block_co),
                          lambda j, i, hb: (0, 0, 0, j)),
         ],

@@ -1,18 +1,30 @@
-"""Fused ULPPACK matmul Pallas TPU kernel — the ``vmacsr`` analogue.
+"""Packed sub-byte matmul Pallas TPU kernel.
 
-The kernel computes  D[M, N] = sum_k dot-extract(a_packed[M, Kp], w_packed[Kp, N])
-where every K-block is processed as ``chunks`` sub-tiles of ``k_tile`` packed
-lanes: each sub-tile is one MXU contraction in packed space, immediately
-followed by the shift-mask extraction (VPU ops on VMEM-resident registers) and
-accumulation into a VMEM s32 accumulator.  This places Sparq's post-multiplier
-shifter at the MXU-tile boundary — the TPU-idiomatic fusion point (DESIGN.md
-§2) — and keeps the packed partials out of HBM entirely, unlike the native
-XLA path (packing.packed_matmul_reference) which round-trips an s32 partial
-per k_tile lanes.
+The kernel computes  D[M, N] = sum_k a[M, K] * w[K, N]  from the P1-packed
+operands the serving path stores: activation lanes [M, Kp] (ascending
+fields, kernels/quant_pack.py) and weight lanes [Kp, N] (field-reversed,
+core/packing.pack_weights).  HBM holds the packed lanes; each K-block is
+unpacked in VMEM by shift-mask into ``n_pack`` int8 field planes, and every
+plane pair is one int8 x int8 -> int32 MXU contraction:
 
-Block layout (output-stationary, matching the paper's Algorithm 1):
-  grid = (M/bm, N/bn, Kp/bk), k innermost; acc[bm, bn] s32 lives in VMEM
-  scratch across the k sweep; bk = chunks * k_tile lanes.
+    D = sum_j  field_j(a) @ field_j(w)
+
+The v5e MXU takes int8 operands only (Mosaic refuses int16/int32 dot
+operands), so the packed lanes are never themselves an MXU operand here —
+the shift-mask that Sparq's ``vmacsr`` applies after the multiply moves to
+the unpack before it, and no extraction band or ``k_tile`` bound applies.
+The XLA backend (kernels/ops.py) keeps the packed-lane dots with extraction;
+both are exact, so they agree bit for bit.
+
+Fields are unsigned lattice values below ``2**bits``.  A field of 8 bits
+does not fit int8, so it is recentred by -128 and the product corrected
+with the other operand's K-block sums (at most one operand of a feasible
+layout is 8 bits wide).
+
+Block layout (output-stationary): grid = (M/bm, N/bn, Kp/bk), k innermost;
+acc[bm, bn] s32 lives in VMEM scratch across the k sweep.  On TPU ``bk``
+and ``bn`` must be multiples of 128 and ``bm`` of 8; kernels/plan.py emits
+only such blocks (DESIGN.md §10).
 """
 
 from __future__ import annotations
@@ -28,27 +40,46 @@ from repro.core.packing import PackSpec
 from repro.kernels import plan as plan_lib
 
 
-def _kernel(a_ref, w_ref, o_ref, acc_ref, *, spec: PackSpec, chunks: int,
-            k_tile: int):
+def _field(x, shift: int, mask: int, bits: int):
+    """One unpacked field plane as an int8 MXU operand, and the recentring
+    offset it carries (128 for 8-bit fields, else 0)."""
+    f = (x >> shift) & mask
+    if bits < 8:
+        return f.astype(jnp.int8), 0
+    return (f - 128).astype(jnp.int8), 128
+
+
+def packed_dot(a, w, spec: PackSpec):
+    """Exact int32 ``a @ w`` of P1 lanes a [R, K] (ascending fields) and
+    w [K, N] (field-reversed): one int8 x int8 MXU contraction per field
+    plane pair.  Also the inner product of ulppack_conv2d."""
+    a = a.astype(jnp.int32)
+    w = w.astype(jnp.int32)
+    out = None
+    for j in range(spec.n_pack):
+        a_j, oa = _field(a, spec.shift * j, spec.field_mask, spec.a_bits)
+        w_j, ow = _field(w, spec.shift * (spec.n_pack - 1 - j),
+                         spec.field_mask, spec.w_bits)
+        t = jax.lax.dot_general(a_j, w_j, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.int32)
+        # undo a recentring: sum_k (a-oa)(w-ow) with one of oa/ow zero
+        if oa:
+            t += oa * jnp.sum(w_j.astype(jnp.int32) + ow, axis=0,
+                              keepdims=True)
+        if ow:
+            t += ow * jnp.sum(a_j.astype(jnp.int32) + oa, axis=1,
+                              keepdims=True)
+        out = t if out is None else out + t
+    return out
+
+
+def _kernel(a_ref, w_ref, o_ref, acc_ref, *, spec: PackSpec):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...]                       # [bm, bk] lane dtype
-    w = w_ref[...]                       # [bk, bn] lane dtype
-    bm, bk = a.shape
-    bn = w.shape[1]
-    # [bm, chunks, k_tile] x [chunks, k_tile, bn] -> [chunks, bm, bn] packed
-    # totals, one batched MXU contraction per K-block.
-    a3 = a.reshape(bm, chunks, k_tile)
-    w3 = w.reshape(chunks, k_tile, bn)
-    totals = jax.lax.dot_general(
-        a3, w3, (((2,), (1,)), ((1,), (0,))),
-        preferred_element_type=jnp.int32)
-    # vmacsr epilogue: shift to the D band, mask, accumulate wide.
-    band = spec.shift * (spec.n_pack - 1)
-    d = (totals >> band) & spec.field_mask
-    acc_ref[...] += jnp.sum(d, axis=0)
+    # [bm, bk] x [bk, bn] packed lanes
+    acc_ref[...] += packed_dot(a_ref[...], w_ref[...], spec)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _done():
@@ -73,10 +104,11 @@ def ulppack_matmul(a_packed: jax.Array, w_packed: jax.Array, spec: PackSpec,
                    interpret: bool | None = None) -> jax.Array:
     """Packed-lane matmul: [M, Kp] x [Kp, N] -> s32 [M, N] exact dot values.
 
-    ``interpret`` defaults from plan.default_interpret(): interpreter on CPU
-    (validation mode), compiled on TPU.
-    VMEM working set per step ~= bm*bk + bk*bn lanes + (chunks+1)*bm*bn s32;
-    defaults stay under 2 MiB for int16 lanes with chunks<=8.
+    ``chunks`` sets the K block: ``chunks * plan.MATMUL_LANES`` packed
+    lanes per grid step.  ``interpret`` defaults from
+    plan.default_interpret(): the interpreter on CPU (validation mode),
+    compiled on TPU.
+    VMEM working set per step: see plan.matmul_working_set.
     """
     if interpret is None:
         interpret = plan_lib.default_interpret()
@@ -87,8 +119,7 @@ def ulppack_matmul(a_packed: jax.Array, w_packed: jax.Array, spec: PackSpec,
     m, kp = a_packed.shape
     kp2, n = w_packed.shape
     assert kp == kp2, (kp, kp2)
-    k_tile = spec.k_tile
-    block_k = chunks * k_tile
+    block_k = chunks * plan_lib.MATMUL_LANES
 
     a_p = _pad_axis(_pad_axis(a_packed, 0, block_m), 1, block_k)
     w_p = _pad_axis(_pad_axis(w_packed, 0, block_k), 1, block_n)
@@ -97,7 +128,7 @@ def ulppack_matmul(a_packed: jax.Array, w_packed: jax.Array, spec: PackSpec,
     gn = w_p.shape[1] // block_n
 
     out = pl.pallas_call(
-        functools.partial(_kernel, spec=spec, chunks=chunks, k_tile=k_tile),
+        functools.partial(_kernel, spec=spec),
         grid=(gm, gn, gk),
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),

@@ -1,11 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) cell on the production mesh with 512 placeholder host devices.
 
-THE TWO LINES ABOVE MUST STAY FIRST — jax locks the device count on first
-init, so the flag must be set before any other import (including repro.*).
+THE THREE LINES ABOVE MUST STAY FIRST — jax locks the device count on
+first init, so the flag must be set before any other import (including
+repro.*).  The platform is pinned to the CPU: the 512 host devices
+describe no real hardware, and the dry-run (and the one child per cell it
+spawns) must never take an accelerator another process is using.
 
 Single-cell mode (used by the orchestrator, one subprocess per cell so a
 crash or RAM spike in one compile cannot take down the sweep):

@@ -6,41 +6,44 @@ jax device state (the dry-run must set XLA_FLAGS before first jax init).
 
 from __future__ import annotations
 
-import warnings
-
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; multi-pod adds a leading 2-pod axis (512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the serving and training
+    code steers GSPMD with ``with_sharding_constraint``, which only accepts
+    Auto axes (make_mesh defaults to Explicit ones)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Tiny mesh over the real host devices (tests / examples).
+    """``(data, model)`` mesh over the host's devices.
 
-    Both axes of the ``(data, model)`` request are validated (>= 1) and
-    infeasible requests are clamped to what the host actually has —
-    loudly: sharding tests that silently ran on a 1x1 mesh were passing
-    without testing anything.
+    Both axes are validated (>= 1), and a request for more devices than
+    the host has is refused: a mesh smaller than asked for would run every
+    shard of a "4-way" layout on fewer chips and report it as four.
     """
     if data < 1 or model < 1:
         raise ValueError(
             f"mesh axes must be >= 1, got (data={data}, model={model})")
     n = len(jax.devices())
-    data_actual = min(data, n)
-    model_actual = min(model, max(1, n // data_actual))
-    if (data_actual, model_actual) != (data, model):
-        warnings.warn(
+    if data * model > n:
+        raise ValueError(
             f"make_host_mesh: requested (data={data}, model={model}) "
-            f"needs {data * model} devices but the host has {n}; "
-            f"clamping to (data={data_actual}, model={model_actual}). "
+            f"needs {data * model} devices but the host has {n}. "
             f"Force more CPU devices with "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=N.",
-            stacklevel=2)
-    return jax.make_mesh((data_actual, model_actual), ("data", "model"))
+            f"XLA_FLAGS=--xla_force_host_platform_device_count=N.")
+    return _auto_mesh((data, model), ("data", "model"),
+                      devices=jax.devices()[:data * model])
 
 
 def make_serving_mesh(model: int = 1, data: int = 1):
@@ -52,8 +55,8 @@ def make_serving_mesh(model: int = 1, data: int = 1):
     replica-fleet axis: serve/router.Router carves the mesh into ``data``
     replica groups of ``model`` devices each (``replica_meshes``) and
     load-balances requests across them (the ``--data-parallel`` knob).
-    Requests beyond the host's device count clamp with the same warning
-    as make_host_mesh.  Testable on CPU via
+    Requests beyond the host's device count are refused, as in
+    make_host_mesh.  Testable on CPU via
     XLA_FLAGS=--xla_force_host_platform_device_count=8 for (data=2,
     model=2) and beyond.
     """
@@ -77,5 +80,6 @@ def replica_meshes(mesh):
             f"expected a ('data', 'model') serving mesh, got axes "
             f"{tuple(mesh.axis_names)}")
     dev = mesh.devices
-    return [jax.sharding.Mesh(dev[i:i + 1], ("data", "model"))
+    return [jax.sharding.Mesh(dev[i:i + 1], ("data", "model"),
+                              axis_types=(AxisType.Auto,) * 2)
             for i in range(dev.shape[0])]

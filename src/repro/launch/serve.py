@@ -34,6 +34,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.serve.config import EngineConfig
 from repro.serve.engine import Request, ServingEngine
@@ -171,6 +172,7 @@ def _fleet_main(args, cfg, params, econf: EngineConfig):
 
 def main():
     args = build_parser().parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_config(args.arch, reduced=args.reduced)
     if args.kv_bits >= 0:
@@ -206,9 +208,6 @@ def main():
     rep = eng.metrics.report()
     rep["capacity"] = eng.capacity_report()
     toks = sum(len(r.output) for r in done)
-    # report the ACTUAL shard count: make_serving_mesh clamps (with a
-    # warning) when the host has fewer devices than --model-parallel asked
-    # for, and labeling those numbers as N-way TP would misattribute them
     shards = eng.shard_plan.model_shards if eng.shard_plan else 1
     print(f"{len(done)} requests, {toks} generated tokens"
           + (f" (model-parallel x{shards})" if shards > 1 else ""))

@@ -32,6 +32,16 @@ from repro.models import lm
 from repro.optim import adamw, schedules
 
 
+#: XLA options of every jitted serving step.  By default XLA may keep a
+#: bf16 intermediate in f32 inside a fusion ("excess precision"), so where
+#: a value is rounded depends on how the program was fused — and a
+#: tensor-parallel step, whose collectives split fusions, would then round
+#: differently from the one-device step and drift to other greedy tokens.
+#: Rounding every bf16 value where the program says keeps the served
+#: tokens independent of the mesh.
+SERVING_XLA_OPTIONS = {"xla_allow_excess_precision": False}
+
+
 def quant_mode_for(cfg, kind: str) -> str:
     if not cfg.quant.enabled:
         return "none"
@@ -193,8 +203,7 @@ def make_prefill_chunk_step(cfg, *, kv_shard_axis: str | None = None):
 
     ``index`` [B] is each slot's write offset (tokens already in its cache
     row); ``valid`` [B] is how many of the window's tokens are real (valid-
-    prefix; 1 lets a decode-phase slot ride along with its single pending
-    token, 0 = dead slot).  Runs the deployed packed path so admission cost
+    prefix; 0 = dead slot).  Runs the deployed packed path so admission cost
     is O(prompt_len / chunk) launches at batched arithmetic intensity
     instead of O(prompt_len) batch-1 decode steps (DESIGN.md §12).
     Returns (last-valid-token logits [B, vocab], new caches).
@@ -334,10 +343,10 @@ def _jitted_serving_steps(cfg, kv_shard_axis, _mesh_key, _fused):
     # keys the memo on the REPRO_FUSED_DECODE kill-switch, which is read
     # at trace time — without it a flipped env var would hit stale traces.
     return (jax.jit(make_decode_step(cfg, kv_shard_axis=kv_shard_axis),
-                    donate_argnums=(1,)),
+                    donate_argnums=(1,), compiler_options=SERVING_XLA_OPTIONS),
             jax.jit(make_prefill_chunk_step(cfg,
                                             kv_shard_axis=kv_shard_axis),
-                    donate_argnums=(1,)))
+                    donate_argnums=(1,), compiler_options=SERVING_XLA_OPTIONS))
 
 
 def jitted_speculative_steps(cfg, draft_cfg, k: int, *,
@@ -363,11 +372,11 @@ def jitted_speculative_steps(cfg, draft_cfg, k: int, *,
 @functools.lru_cache(maxsize=None)
 def _jitted_draft_step(cfg, k, kv_shard_axis, _mesh_key, _fused):
     return jax.jit(make_draft_step(cfg, k, kv_shard_axis=kv_shard_axis),
-                   donate_argnums=(1,))
+                   donate_argnums=(1,), compiler_options=SERVING_XLA_OPTIONS)
 
 
 @functools.lru_cache(maxsize=None)
 def _jitted_verify_step(cfg, kv_shard_axis, _mesh_key, _fused):
     return jax.jit(make_verify_chunk_step(cfg,
                                           kv_shard_axis=kv_shard_axis),
-                   donate_argnums=(1,))
+                   donate_argnums=(1,), compiler_options=SERVING_XLA_OPTIONS)

@@ -260,18 +260,18 @@ def _fused_decode_epilogue(p, cfg, q, read_cache, valid_len, positions,
     paged view, nor a full score block materializes.  ``valid_len`` [B] is
     each row's live logical-view prefix; the group mask
     ``pos < valid_len & pos <= qpos`` is exactly the legacy
-    ``_ring_positions*`` visibility for non-windowed caches.  Sharded
-    serving (``kv_shard_axis``) pins the 'xla' backend — the only GSPMD-
-    partitionable one."""
+    ``_ring_positions*`` visibility for non-windowed caches.  Under
+    sharded serving (``kv_shard_axis``) GSPMD partitions the 'xla'
+    backend, and the Pallas kernel runs per kv-head shard under
+    shard_map."""
     from repro.kernels import ulppack_attention
 
     b, sq, h, hd = q.shape
     if positions.ndim == 1:
         positions = jnp.broadcast_to(positions[None, :], (b, sq))
-    backend = "xla" if kv_shard_axis is not None else "auto"
     out = ulppack_attention.fused_decode_attention(
         q, read_cache, valid_len, positions, kv_bits=kv_bits, hd=hd,
-        block_tables=block_tables, backend=backend)
+        block_tables=block_tables, shard_axis=kv_shard_axis)
     out = dense_apply(p["o"], out.reshape(b, sq, h * hd), **qm)
     return out, new_cache
 
@@ -474,7 +474,29 @@ def attention_apply(p, cfg, x, *, positions, quant_mode="none",
     else:
         # ---- training / prefill ----
         kv_fn = lambda: (k, v)  # attends over the raw (unquantized) k/v
-        if cache is not None:  # prefill fills the cache
+        stored = None
+        if (quant_mode == "packed" and kv_bits in (8, 4, 2)
+                and kv_x is None and causal and not window):
+            # the deployed model attends to K/V as its cache stores them
+            # (DESIGN.md §13): the packed forward quantizes this window's
+            # K/V at the cache precision and reads them back the way
+            # decode does — the fused read, or the legacy dequantizing
+            # read under the REPRO_FUSED_DECODE=0 kill-switch
+            stored = {}
+            stored["k"], stored["k_scale"] = _kv_quantize(k, kv_bits)
+            stored["v"], stored["v_scale"] = _kv_quantize(v, kv_bits)
+            if cache is not None:
+                new_cache = _constrain_kv_heads(
+                    _cache_write(cache, k, v, 0, kv_bits), kv_shard_axis)
+            from repro.kernels import ulppack_attention
+            if ulppack_attention.enabled():
+                causal_idx = jnp.broadcast_to(jnp.arange(sq)[None, :],
+                                              (b, sq))
+                return _fused_decode_epilogue(
+                    p, cfg, q, stored, jnp.full((b,), sq, jnp.int32),
+                    causal_idx, kv_bits, new_cache, qm, None)
+            kv_fn = lambda: _cache_read(stored, k.dtype, kv_bits, hd)
+        if cache is not None and stored is None:  # prefill fills the cache
             size = cache["k"].shape[1]
             if window and sq > size:
                 # ring layout: slot = pos % size for the last `size` tokens

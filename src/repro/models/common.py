@@ -19,6 +19,7 @@ import numpy as np
 from repro.core import quant
 from repro.core.packing import PackSpec
 from repro.core.quant import QuantConfig
+from repro.core.reduce import ordered_sum
 from repro.kernels import ops
 
 
@@ -155,7 +156,8 @@ def rmsnorm_init(d, dtype=jnp.float32):
 def rmsnorm_apply(p, x, eps=1e-5):
     dt = x.dtype
     x32 = x.astype(jnp.float32)
-    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    # fixed-order sum: a row normalizes the same in every window shape
+    var = ordered_sum(x32 * x32, keepdims=True) / x.shape[-1]
     y = x32 * jax.lax.rsqrt(var + eps)
     return (y * p["scale"].astype(jnp.float32)).astype(dt)
 
@@ -196,8 +198,6 @@ def embedding_apply(p, tokens, compute_dtype=jnp.bfloat16):
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.parallel.sharding import shard_map
-
     dp = tuple(a for a in ("pod", "data") if a in mesh.shape)
     bspec = dp if dp and tokens.shape[0] % shlib._axis_size(mesh, dp) == 0 \
         else None
@@ -212,7 +212,7 @@ def embedding_apply(p, tokens, compute_dtype=jnp.bfloat16):
         emb = emb * ok[..., None].astype(compute_dtype)
         return jax.lax.psum(emb, "model")
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("model", None), P(bspec, None)),
         out_specs=P(bspec, None, None),

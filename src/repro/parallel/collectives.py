@@ -81,8 +81,6 @@ def all_gather_matmul(x, w, mesh, axis: str = "model"):
     x: [m, k/P] sharded on its last dim over `axis`; w: [k/P, n] sharded on
     its first dim.  Returns y [m, n] replicated over `axis`.
     """
-    from repro.parallel.sharding import shard_map
-
     p = mesh.shape[axis]
 
     def local(x_l, w_l):
@@ -106,7 +104,7 @@ def all_gather_matmul(x, w, mesh, axis: str = "model"):
         acc, _ = jax.lax.fori_loop(0, p, body, (acc0, x_l))
         return acc
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, axis), P(axis, None)),
         out_specs=P(None, None), check_vma=False)(x, w)

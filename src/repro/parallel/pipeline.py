@@ -73,11 +73,7 @@ def gpipe(stage_fn, stage_params, xs, *, mesh, axis: str = "pod"):
             axis)
         return outs
 
-    from repro.parallel.sharding import shard_map
-
-    other_axes = [a for a in mesh.axis_names if a != axis]
-    del other_axes
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P(*([None] * xs.ndim))),
         out_specs=P(*([None] * xs.ndim)),

@@ -25,26 +25,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    The public ``jax.shard_map`` (with its ``check_vma`` replication check)
-    only exists from jax 0.5; on the pinned 0.4.x toolchain the same
-    transform lives at ``jax.experimental.shard_map.shard_map`` and spells
-    the flag ``check_rep``.  Every shard_map in the repo routes through
-    here so multi-device code (pipeline, collectives, sharded-vocab embed)
-    runs on both — the seed-failing subprocess lowerings were exactly this
-    AttributeError."""
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_vma=check_vma)
-
-
 def _axis_size(mesh: Mesh, axis) -> int:
     if axis is None:
         return 1
@@ -98,12 +78,18 @@ class activation_mesh:
         _ACTIVE_MESH.pop()
 
 
-def constrain(x, *axes):
-    """with_sharding_constraint(x, P(axes...)) with 'dp' meta-axis resolution
-    and divisibility guarding; no-op without an active mesh."""
+def kernel_mesh():
+    """The active mesh when it spans several devices, else None.  GSPMD
+    cannot partition a Mosaic custom call, so under such a mesh every
+    Pallas kernel runs per device inside ``jax.shard_map``."""
     mesh = _ACTIVE_MESH[-1]
-    if mesh is None:
-        return x
+    return mesh if mesh is not None and mesh.size > 1 else None
+
+
+def spec_on_mesh(mesh: Mesh, shape, *axes) -> P:
+    """P(axes...) for an array of ``shape`` on ``mesh``: the 'dp' meta-axis
+    resolves to the mesh's data axes, and axes that do not divide their
+    dim drop (the array replicates along them)."""
     resolved = []
     for a in axes:
         if a == "dp":
@@ -111,9 +97,17 @@ def constrain(x, *axes):
             resolved.append(dp if dp else None)
         else:
             resolved.append(a)
-    spec = _guard(mesh, x.shape, P(*resolved))
+    return _guard(mesh, shape, P(*resolved))
+
+
+def constrain(x, *axes):
+    """with_sharding_constraint(x, spec_on_mesh(mesh, x.shape, *axes));
+    no-op without an active mesh."""
+    mesh = _ACTIVE_MESH[-1]
+    if mesh is None:
+        return x
     return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, spec))
+        x, NamedSharding(mesh, spec_on_mesh(mesh, x.shape, *axes)))
 
 
 def constrain_like_params(tree, cfg):

@@ -8,9 +8,10 @@ at batched arithmetic intensity instead of O(prompt_len) batch-1 decode
 steps — and live slots decode lockstep-free: every slot carries its own
 position, cache writes land at per-slot offsets (``cache_valid`` /
 vector ``cache_index`` in models/lm.forward), and sampling (greedy /
-temperature / top-k) is per slot.  Decode-phase slots ride along inside
-prefill passes with their single pending token, finished sequences retire
-immediately, and freed slots are re-admitted the same step.
+temperature / top-k) is per slot.  While some slots prefill, decode-phase
+slots take the same scheduler tick in a decode launch of their own,
+finished sequences retire immediately, and freed slots are re-admitted
+the same step.
 
 Both steps run the paper's packed integer kernels via
 prepare.prepare_serving_params (quant_mode='packed'); KernelPlans for the
@@ -69,10 +70,10 @@ class Metrics:
 
     ``prefill_tokens`` counts prompt tokens consumed by chunked prefill;
     ``generated_tokens`` counts every sampled token; ``decode_tokens``
-    only those sampled in pure decode passes, so decode_tok_s divides
-    tokens by the wall time of the same passes.  Tokens sampled inside a
-    mixed prefill pass (decode riders, first token after a prompt
-    completes) count as generated but land in the prefill time bucket.
+    only those sampled in decode launches, so decode_tok_s divides
+    tokens by the wall time of the same launches.  A request's first
+    token, sampled in the prefill launch that completes its prompt,
+    counts as generated and lands in the prefill time bucket.
 
     Per-request latency: ``ttft_s`` records one time-to-first-token sample
     per request (submit -> first sampled token, so queue wait counts —
@@ -498,9 +499,10 @@ class ServingEngine:
     # ------------------------------------------------------------------
 
     def step(self) -> bool:
-        """One scheduler tick: admit, then one batched model pass —
-        chunked prefill while any slot is mid-prompt (decode-phase slots
-        ride along), else a single-token ragged decode."""
+        """One scheduler tick: admit, then the batched model passes —
+        chunked prefill while any slot is mid-prompt (followed by a decode
+        launch for the decode-phase slots), else a single-token ragged
+        decode."""
         self._admit()
         live = [s for s in range(self.max_batch)
                 if self.slot_req[s] is not None]
@@ -522,9 +524,13 @@ class ServingEngine:
                 for s in live)
         t0 = time.perf_counter()
         if prefilling:
-            n_prompt = self._prefill_pass(live)
-            self.metrics.prefill_time_s += time.perf_counter() - t0
+            n_prompt, decoding = self._prefill_pass(live)
+            t1 = time.perf_counter()
+            self.metrics.prefill_time_s += t1 - t0
             self.metrics.prefill_tokens += n_prompt
+            if decoding:
+                self._decode_pass(decoding)
+                self.metrics.decode_time_s += time.perf_counter() - t1
         elif self.spec is not None:
             self._speculative_pass(live)
             self.metrics.decode_time_s += time.perf_counter() - t0
@@ -538,12 +544,19 @@ class ServingEngine:
         return jnp.asarray(
             np.broadcast_to(pos[None], (3, self.max_batch, width)).copy())
 
-    def _prefill_pass(self, live) -> int:
+    def _prefill_pass(self, live) -> tuple[int, list]:
+        """The prefill-chunk launch for slots still mid-prompt.  Returns
+        (prompt tokens consumed, decode-phase slots).  Those slots are
+        dead rows here and take their step in the decode program right
+        after: a row's numbers then never depend on which program it rode
+        in, so a request gets the same tokens whatever it is batched with
+        (DESIGN.md §12)."""
         c = self.prefill_chunk
         tokens = np.zeros((self.max_batch, c), np.int32)
         index = np.zeros(self.max_batch, np.int32)
         valid = np.zeros(self.max_batch, np.int32)
         take = {}
+        decoding = []
         n_prompt = 0
         for s in live:
             req = self.slot_req[s]
@@ -555,9 +568,8 @@ class ServingEngine:
                 tokens[s, :t] = req.prompt[fed:fed + t]
                 valid[s] = take[s] = t
                 n_prompt += t
-            elif req.output:   # decode-phase rider: one pending token
-                tokens[s, 0] = req.output[-1]
-                valid[s] = 1
+            elif req.output:   # decode phase: steps in the decode program
+                decoding.append(s)
             # else: target prompt done but the first token is stashed
             # until the speculative draft finishes its full-prompt
             # replay — a dead slot (valid 0) in this target pass
@@ -594,15 +606,12 @@ class ServingEngine:
                         # prefix sharing let the target finish before the
                         # draft's full replay: park the first-token logits
                         self.spec.stash(s, logits[s])
-            elif req.output:
-                self.slot_pos[s] += 1
-                self._emit_token(s, logits[s], decode_pass=False)
             elif self.spec is not None and self.spec.has_stash(s) \
                     and self.spec.prompt_done(s, req):
                 # the draft just caught up: emit the parked first token
                 self._emit_token(s, self.spec.pop_stash(s),
                                  decode_pass=False)
-        return n_prompt
+        return n_prompt, decoding
 
     def _draft_prefill(self, live):
         """Feed the speculative draft cache its own prefill window:

@@ -31,7 +31,7 @@ class TestCacheFile:
     def test_save_load_roundtrip(self, tmp_path):
         c = autotune.TuningCache(device="cpu")
         key = autotune.matmul_key(8, 32, 64, SPEC, backend="xla")
-        c.store(key, {"block_m": 32, "block_n": 64, "chunks": 2})
+        c.store(key, {"block_m": 32, "block_n": 128, "chunks": 2})
         path = c.save(str(tmp_path / "cache.json"))
         back = autotune.TuningCache.load(path)
         assert back is not None
@@ -71,24 +71,25 @@ class TestPlannerConsultation:
     def test_hit_returns_cache_backed_plan(self):
         c = _empty()
         c.store(autotune.matmul_key(8, 32, 64, SPEC, backend="xla"),
-                {"block_m": 32, "block_n": 64, "chunks": 2})
+                {"block_m": 32, "block_n": 128, "chunks": 2})
         plan = plan_lib.plan_packed_matmul(8, 32, 64, SPEC, backend="xla")
         assert plan.source == "tuned"
-        assert (plan.block_m, plan.block_n, plan.chunks) == (32, 64, 2)
+        assert (plan.block_m, plan.block_n, plan.chunks) == (32, 128, 2)
         # vmem estimate recomputed from the planner's own accounting
-        assert plan.vmem_bytes == plan_lib.matmul_working_set(32, 64, 2,
+        assert plan.vmem_bytes == plan_lib.matmul_working_set(32, 128, 2,
                                                               SPEC)
 
     def test_miss_falls_back_to_heuristic(self):
         _empty()
         plan = plan_lib.plan_packed_matmul(8, 32, 64, SPEC, backend="xla")
         assert plan.source == "heuristic"
-        assert plan.block_m == 128
+        # TPU-aligned blocks: rows rounded to 8, N and K in 128 lanes
+        assert (plan.block_m, plan.block_n, plan.chunks) == (8, 128, 1)
 
     def test_use_tuning_cache_false_bypasses_hit(self):
         c = _empty()
         c.store(autotune.matmul_key(8, 32, 64, SPEC, backend="xla"),
-                {"block_m": 32, "block_n": 64, "chunks": 2})
+                {"block_m": 32, "block_n": 128, "chunks": 2})
         plan = plan_lib.plan_packed_matmul(8, 32, 64, SPEC, backend="xla",
                                            use_tuning_cache=False)
         assert plan.source == "heuristic"
@@ -99,6 +100,17 @@ class TestPlannerConsultation:
                 {"block_m": 4096, "block_n": 4096, "chunks": 16})
         plan = plan_lib.plan_packed_matmul(8, 32, 64, SPEC, backend="xla")
         assert plan.source == "heuristic"
+
+    def test_misaligned_entry_ignored(self):
+        """A tuned tile the TPU compiler would refuse (N block not a
+        multiple of 128 lanes) never becomes a plan."""
+        c = _empty()
+        c.store(autotune.matmul_key(8, 32, 64, SPEC, backend="xla"),
+                {"block_m": 32, "block_n": 64, "chunks": 2})
+        plan = plan_lib.plan_packed_matmul(8, 32, 64, SPEC, backend="xla")
+        assert plan.source == "heuristic"
+        assert plan_lib.matmul_tiles_ok(plan.block_m, plan.block_n,
+                                        plan.chunks)
 
     def test_malformed_entry_ignored(self):
         c = _empty()
@@ -125,7 +137,7 @@ class TestPlannerConsultation:
     def test_plan_selection_deterministic_given_fixed_cache(self):
         c = _empty()
         c.store(autotune.matmul_key(8, 32, 64, SPEC, backend="xla"),
-                {"block_m": 16, "block_n": 32, "chunks": 4})
+                {"block_m": 16, "block_n": 128, "chunks": 4})
         a = plan_lib.plan_packed_matmul(8, 32, 64, SPEC, backend="xla")
         plan_lib.clear_plan_cache()
         b = plan_lib.plan_packed_matmul(8, 32, 64, SPEC, backend="xla")

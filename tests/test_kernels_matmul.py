@@ -30,6 +30,9 @@ SPECS = [
     PackSpec(3, 3, jnp.int16.dtype),
     PackSpec(4, 3, jnp.int16.dtype),
     PackSpec(1, 1, jnp.int16.dtype, n_pack=4),
+    # 8-bit fields exceed int8: the kernel recentres them (one side only)
+    PackSpec(8, 1, jnp.int32.dtype, 2, 16),
+    PackSpec(1, 8, jnp.int32.dtype, 2, 16),
 ]
 
 
@@ -92,7 +95,10 @@ class TestIntMatmulKernel:
 
 class TestQuantizePackKernel:
     @pytest.mark.parametrize("spec", [PackSpec(2, 2, jnp.int16.dtype),
-                                      PackSpec(1, 1, jnp.int8.dtype)], ids=str)
+                                      PackSpec(1, 1, jnp.int8.dtype),
+                                      PackSpec(1, 8, jnp.int32.dtype, 2, 16),
+                                      PackSpec(2, 2, jnp.int32.dtype, 4, 8)],
+                             ids=str)
     def test_matches_ref(self, spec):
         rng = np.random.default_rng(5)
         x = jnp.asarray(rng.normal(size=(37, 129)), jnp.float32)

@@ -15,8 +15,8 @@ def subprocess_env():
     jax backend pins passed through: without them the child falls into
     backend autodetection, which can hang for minutes (or grab a device)
     on hosts that pin JAX_PLATFORMS — the seed-failing env assumption."""
-    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"}
-    for var in ("JAX_PLATFORMS", "JAX_PLATFORM_NAME"):
+    env = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}
+    for var in ("JAX_PLATFORMS", "JAX_PLATFORM_NAME", "HOME", "TMPDIR"):
         if var in os.environ:
             env[var] = os.environ[var]
     return env

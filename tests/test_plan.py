@@ -82,6 +82,22 @@ class TestPlanner:
             sig = inspect.signature(fn)
             assert sig.parameters["interpret"].default is None, fn
 
+    @pytest.mark.parametrize("m,kp,n", [(1, 32, 64), (8, 1024, 2048),
+                                        (256, 2816, 2048), (13, 100, 7),
+                                        (3072, 1024, 5632)])
+    def test_matmul_blocks_tpu_aligned(self, m, kp, n):
+        """Every heuristic packed-matmul plan uses blocks the TPU compiler
+        accepts (rows in 8s, N and K in 128-lane units), and the K block
+        divides the 128-rounded packed K so weights are never re-padded."""
+        p = plan_lib.plan_packed_matmul(m, kp, n, SPEC, backend="pallas")
+        assert plan_lib.matmul_tiles_ok(p.block_m, p.block_n, p.chunks)
+        assert p.block_m >= min(m, 128)
+        assert (-(-kp // plan_lib.MATMUL_LANES)) % p.chunks == 0
+        small = plan_lib.plan_packed_matmul(m, kp, n, SPEC, backend="pallas",
+                                            vmem_budget=300 * 1024)
+        assert plan_lib.matmul_tiles_ok(small.block_m, small.block_n,
+                                        small.chunks)
+
     def test_describe_reports_tiles(self):
         p = plan_lib.plan_packed_conv2d((1, 64, 64, 16), (7, 7, 16, 32),
                                         SPEC, padding="SAME", backend="xla")

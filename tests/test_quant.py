@@ -131,3 +131,20 @@ class TestVmacsrISA:
         base = vmacsr.int16_instruction_count(256)
         assert fused.total < native.total < base.total * 2
         assert fused.shifts == 0 and native.shifts > 0
+
+
+@pytest.mark.parametrize("n", [1, 3, 64, 100, 2048])
+def test_ordered_sum_matches_sum_and_ignores_batch_shape(n):
+    """core/reduce.ordered_sum is a sum (to f32 rounding), and a row's
+    result is bit-identical whether it is reduced among 8 rows or 200."""
+    from repro.core.reduce import ordered_sum
+
+    x = np.random.default_rng(n).normal(size=(200, n)).astype(np.float32)
+    got = np.asarray(jax.jit(ordered_sum)(jnp.asarray(x)))
+    np.testing.assert_allclose(got, x.sum(-1, dtype=np.float64),
+                               rtol=1e-5, atol=1e-4)
+    few = np.asarray(jax.jit(ordered_sum)(jnp.asarray(x[:8])))
+    assert np.array_equal(few, got[:8])
+    kept = ordered_sum(jnp.asarray(x.T), axis=0, keepdims=True)
+    assert kept.shape == (1, 200)
+    np.testing.assert_array_equal(np.asarray(kept)[0], got)

@@ -310,6 +310,39 @@ def test_engine_staggered_admission_matches_single_request(chunk):
     assert staggered == sequential
 
 
+@pytest.mark.parametrize("kv_bits", [4, 2])
+def test_engine_tokens_independent_of_batch_mates(kv_bits):
+    """On the deployed path (packed kernels, paged sub-byte cache) a
+    request's greedy tokens do not depend on what it shares steps with:
+    prompts of mixed lengths served together equal the same prompts
+    served one at a time — what a replica fleet relies on to match one
+    engine.  Decode-phase slots step in the decode program while others
+    prefill, so each row is always computed by the same program (on a
+    TPU the two programs read the cache with different kernels)."""
+    from repro.serve.engine import Request, ServingEngine
+
+    cfg = configs.get_config("stablelm-1.6b", reduced=True)
+    cfg = cfg.replace(quant=cfg.quant.replace(kv_bits=kv_bits))
+    params = lm.init_params(jax.random.PRNGKey(3), cfg)
+    rng = np.random.default_rng(20 + kv_bits)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (4, 29, 13, 22)]
+    eng = ServingEngine(cfg, params, config=EngineConfig(
+        max_batch=4, max_len=40, paged=True, prefill_chunk=8))
+
+    def serve(batch):
+        for i in batch:
+            assert eng.submit(Request(uid=i, prompt=prompts[i],
+                                      max_new_tokens=8))
+        return {r.uid: tuple(r.output) for r in eng.run_to_completion()}
+
+    together = serve(range(len(prompts)))
+    alone = {}
+    for i in range(len(prompts)):
+        alone.update(serve([i]))
+    assert together == alone
+
+
 def test_run_to_completion_collects_same_step_finishers():
     """A request with max_new_tokens=1 whose whole prompt fits one prefill
     chunk is admitted, prefilled, and retired inside a single step(); the
@@ -406,3 +439,35 @@ def test_int8_kv_cache_decode_accuracy():
     dec = _decode_all(cfg, params, tokens, max_len=16)
     np.testing.assert_allclose(np.asarray(dec), np.asarray(full[:, -1]),
                                rtol=0.05, atol=0.05)
+
+
+@pytest.mark.parametrize("kv_bits", [4, 2, 8])
+def test_engine_first_token_matches_uncached_packed_forward(kv_bits):
+    """The paged engine's first token (chunked prefill through the packed
+    kernels and the quantized page pool) is the argmax of one uncached
+    packed forward of the prompt alone, in the real bf16 compute dtype:
+    the uncached forward attends to its K/V at the cache precision, and
+    every float sum on the path runs in a fixed order, so chunking,
+    paging and batching change no rounding."""
+    from repro.serve.engine import Request, ServingEngine
+
+    cfg = configs.get_config("stablelm-1.6b", reduced=True)
+    cfg = cfg.replace(quant=cfg.quant.replace(kv_bits=kv_bits))
+    params = lm.init_params(jax.random.PRNGKey(7), cfg)
+    rng = np.random.default_rng(kv_bits)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (5, 16, 37, 21, 30, 9)]
+    eng = ServingEngine(cfg, params, config=EngineConfig(
+        max_batch=3, max_len=48, paged=True, prefill_chunk=8))
+    for i, p in enumerate(prompts):
+        eng.submit(Request(uid=i, prompt=p, max_new_tokens=1))
+    first = {r.uid: r.output[0] for r in eng.run_to_completion()}
+    # compiled like the serving steps (eager op-by-op XLA rounds
+    # differently from any fused program)
+    forward = jax.jit(
+        lambda params, tokens: lm.forward(params, cfg, {"tokens": tokens},
+                                          quant_mode="packed")[0],
+        compiler_options=steps_lib.SERVING_XLA_OPTIONS)
+    for i, p in enumerate(prompts):
+        logits = forward(eng.params, jnp.asarray(p)[None])
+        assert first[i] == int(jnp.argmax(logits[0, -1])), i

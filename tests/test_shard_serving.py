@@ -12,6 +12,9 @@ Multi-device tests skip below 4 devices so the plain tier-1 run stays
 green on 1-device hosts; the warning/spec tests run anywhere.
 """
 
+import subprocess
+import sys
+import textwrap
 import warnings
 
 import jax
@@ -28,6 +31,7 @@ from repro.parallel import sharding
 from repro.serve.config import EngineConfig
 from repro.serve.engine import Request, ServingEngine
 from repro.serve.shard import ShardPlan
+from test_pipeline import subprocess_env
 
 pytestmark = pytest.mark.shard
 
@@ -252,10 +256,11 @@ def test_sharded_plans_cover_dispatch_signatures():
 
 
 def test_host_mesh_clamp_warns():
-    """make_host_mesh names requested vs actual shape instead of clamping
-    silently (satellite); feasible requests stay silent."""
+    """make_host_mesh refuses a mesh larger than the host, naming the
+    requested vs actual shape (a clamped mesh would run an "N-way" layout
+    on fewer devices); feasible requests stay silent."""
     n = len(jax.devices())
-    with pytest.warns(UserWarning, match=rf"requested \(data={2 * n}, "
+    with pytest.raises(ValueError, match=rf"requested \(data={2 * n}, "
                                          rf"model=4\).*has {n}"):
         make_host_mesh(data=2 * n, model=4)
     with warnings.catch_warnings():
@@ -267,3 +272,105 @@ def test_host_mesh_clamp_warns():
 def test_serving_mesh_validates():
     with pytest.raises(ValueError):
         make_serving_mesh(0)
+
+
+# ---------------------------------------------------------------------------
+# Tier-1 guard: 4-way tensor-parallel serving on forced host devices
+# ---------------------------------------------------------------------------
+
+TP_SCRIPT = textwrap.dedent("""
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp, numpy as np
+    from repro import configs
+    from repro.kernels import ops, ulppack_attention
+    from repro.kernels import plan as plan_lib
+    from repro.launch import serve
+    from repro.launch.mesh import make_serving_mesh
+    from repro.models import lm
+    from repro.parallel.sharding import activation_mesh
+    from repro.serve.config import EngineConfig
+    from repro.serve.engine import Request, ServingEngine
+
+    argv = ["--arch", "stablelm-1.6b", "--reduced", "--model-parallel", "4",
+            "--kv-bits", "4", "--paged-kv", "--max-batch", "2",
+            "--max-len", "48", "--prefill-chunk", "8", "--requests", "3",
+            "--max-new-tokens", "6", "--prompt-len", "9"]
+    sys.argv = ["serve"] + argv
+    serve.main()                          # the CLI path end to end
+
+    args = serve.build_parser().parse_args(argv)
+    cfg = configs.get_config(args.arch, reduced=True)
+    cfg = cfg.replace(quant=cfg.quant.replace(kv_bits=args.kv_bits))
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (9, 23, 4)]
+
+    def tokens(mesh):
+        eng = ServingEngine(cfg, params, mesh=mesh,
+                            config=EngineConfig.from_args(args))
+        for i, p in enumerate(prompts):
+            eng.submit(Request(uid=i, prompt=p, max_new_tokens=6))
+        return {r.uid: r.output for r in eng.run_to_completion()}
+
+    single = tokens(None)
+    tp = tokens(make_serving_mesh(args.model_parallel))
+    assert tp == single, (tp, single)
+
+    # the Pallas kernels run per 'model' shard under shard_map (interpreted
+    # here) and agree with the one-device kernels
+    mesh = make_serving_mesh(4)
+    spec = ops.PackSpec(2, 2, jnp.int16)
+    a = ops.packing.pack_activations(
+        jnp.asarray(rng.integers(0, 4, (8, 64)), jnp.int32), spec)
+    w = ops.packing.pack_weights(
+        jnp.asarray(rng.integers(0, 4, (64, 512)), jnp.int32), spec)
+    plan = plan_lib.plan_packed_matmul(8, 32, 512, spec, backend="pallas")
+    one = ops.packed_matmul(a, w, spec, plan=plan)
+    with activation_mesh(mesh):
+        sharded = jax.jit(lambda a, w: ops.packed_matmul(
+            a, w, spec, plan=plan))(a, w)
+    np.testing.assert_array_equal(np.asarray(one), np.asarray(sharded))
+
+    b, h, hd, ps, n_pages = 2, 8, 16, 16, 3
+    pool = b * n_pages
+    cache = {
+        "k": jnp.asarray(rng.integers(-2**31, 2**31, (pool, ps, h, 2)),
+                         jnp.int32),
+        "v": jnp.asarray(rng.integers(-2**31, 2**31, (pool, ps, h, 2)),
+                         jnp.int32),
+        "k_scale": jnp.asarray(rng.uniform(.02, .2, (pool, ps, h)),
+                               jnp.bfloat16),
+        "v_scale": jnp.asarray(rng.uniform(.02, .2, (pool, ps, h)),
+                               jnp.bfloat16)}
+    bt = jnp.asarray(rng.permutation(pool).reshape(b, n_pages), jnp.int32)
+    valid = jnp.asarray([40, 17], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, 1, h, hd)), jnp.bfloat16)
+
+    def attend(q, cache, valid, bt, axis):
+        return ulppack_attention.fused_decode_attention(
+            q, cache, valid, valid[:, None] - 1, kv_bits=4, hd=hd,
+            block_tables=bt, backend="pallas", shard_axis=axis)
+
+    one = attend(q, cache, valid, bt, None)
+    with activation_mesh(mesh):
+        sharded = jax.jit(lambda *xs: attend(*xs, "model"))(
+            q, cache, valid, bt)
+    np.testing.assert_array_equal(np.asarray(one, np.float32),
+                                  np.asarray(sharded, np.float32))
+    print("TP_OK", len(jax.devices()))
+""")
+
+
+def test_tensor_parallel_serving_cli_subprocess():
+    """Reduced stablelm-1.6b at --model-parallel 4 on four forced host
+    devices: the CLI runs, greedy tokens equal the one-device engine's
+    (bf16 compute, paged 4-bit KV), and the Pallas matmul and decode
+    attention, run per shard under shard_map, equal their one-device
+    results.  Runs in a subprocess so the device-count flag stays there —
+    the in-process shard tests skip on a one-device tier-1 run."""
+    r = subprocess.run([sys.executable, "-c", TP_SCRIPT],
+                       capture_output=True, text=True, timeout=600,
+                       env=subprocess_env())
+    assert "TP_OK 4" in r.stdout, (r.stdout[-2000:], r.stderr[-3000:])
