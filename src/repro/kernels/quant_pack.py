@@ -114,7 +114,7 @@ def quantize_pack(x: jax.Array, scale: jax.Array, zero_point: jax.Array,
             jax.ShapeDtypeStruct((x_p.shape[0], 1), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((block_m, 1), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret, name="quantize_pack",
     )(x_p, s, z)
     kp = -(-k // spec.n_pack)
     return packed[:m, :kp], row_sum[:m]

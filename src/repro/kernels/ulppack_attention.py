@@ -433,7 +433,7 @@ def _attention_decode_pallas(plan, q, cache, valid_len, qpos,
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, per, h, width), jnp.float32),
-        interpret=plan.interpret,
+        interpret=plan.interpret, name="ulppack_attention_decode",
     )(*prefetch, qbd, qsum, onehot, ks, vs, *scales)
     # each head's output is the diagonal (own kv head) block of acc
     out = out.reshape(b, per, kvh, groups, kvh, hdw)

@@ -158,7 +158,7 @@ def _tiled_conv_call(kernel, x, w, *, fh, fw, block_h, block_co, out_h,
         out_shape=jax.ShapeDtypeStruct(
             (n, n_bh * block_h, out_w, w.shape[-1]), jnp.int32),
         scratch_shapes=list(scratch_shapes),
-        interpret=interpret,
+        interpret=interpret, name="ulppack_conv2d",
     )(x, w)
     return out[:, :out_h, :, :co]
 

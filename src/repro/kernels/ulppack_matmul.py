@@ -138,7 +138,7 @@ def ulppack_matmul(a_packed: jax.Array, w_packed: jax.Array, spec: PackSpec,
         out_shape=jax.ShapeDtypeStruct((a_p.shape[0], w_p.shape[1]),
                                        jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret, name="ulppack_matmul",
     )(a_p, w_p)
     return out[:m, :n]
 
@@ -185,6 +185,6 @@ def int_matmul(q_a: jax.Array, q_w: jax.Array, *, block_m: int = 128,
         out_shape=jax.ShapeDtypeStruct((a_p.shape[0], w_p.shape[1]),
                                        jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret, name="ulppack_matmul",
     )(a_p, w_p)
     return out[:m, :n]

@@ -19,6 +19,10 @@ replica groups behind one load-balanced front door:
         python -m repro.launch.serve --arch stablelm-1.6b --reduced \
         --data-parallel 2 --model-parallel 2 --metrics
 
+``--trace-dir DIR`` records a profiler trace of the serving run (the
+engine's host spans and the model's device scopes, serve/engine.py) into
+DIR, for TensorBoard's profile plugin or ``jax.profiler.ProfileData``.
+
 Flags are grouped (engine / sampling / quantization / parallelism /
 fleet) and the engine side is derived through a single
 ``EngineConfig.from_args`` call, so the CLI and programmatic
@@ -28,6 +32,7 @@ construction cannot drift.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 
 import jax
@@ -51,6 +56,10 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=6)
     ap.add_argument("--max-new-tokens", type=int, default=8)
+    ap.add_argument("--trace-dir", default=None,
+                    help="record a profiler trace of the serving run "
+                         "(engine spans, device scopes) into this "
+                         "directory")
     ap.add_argument("--metrics", action="store_true",
                     help="print the full metrics report (throughput split "
                          "by phase, occupancy, per-request TTFT and "
@@ -138,6 +147,14 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
+def _traced(trace_dir):
+    """A profiler trace into ``trace_dir`` around the serving run, or
+    nothing without one."""
+    if not trace_dir:
+        return contextlib.nullcontext()
+    return jax.profiler.trace(trace_dir)
+
+
 def _fleet_main(args, cfg, params, econf: EngineConfig):
     from repro.launch.mesh import make_serving_mesh
     from repro.serve.router import Router
@@ -153,7 +170,8 @@ def _fleet_main(args, cfg, params, econf: EngineConfig):
                 np.int32),
             max_new_tokens=args.max_new_tokens,
             session=f"session-{i % 2}")
-    done = router.run_to_completion()
+    with _traced(args.trace_dir):
+        done = router.run_to_completion()
     rep = router.metrics_report()
     rep["capacity"] = router.capacity_report()
     toks = sum(len(h.output) for h in done)
@@ -204,7 +222,8 @@ def main():
             prompt=rng.integers(0, cfg.vocab_size, args.prompt_len).astype(
                 np.int32),
             max_new_tokens=args.max_new_tokens))
-    done = eng.run_to_completion()
+    with _traced(args.trace_dir):
+        done = eng.run_to_completion()
     rep = eng.metrics.report()
     rep["capacity"] = eng.capacity_report()
     toks = sum(len(r.output) for r in done)

@@ -267,12 +267,14 @@ def _fused_decode_epilogue(p, cfg, q, read_cache, valid_len, positions,
     from repro.kernels import ulppack_attention
 
     b, sq, h, hd = q.shape
-    if positions.ndim == 1:
-        positions = jnp.broadcast_to(positions[None, :], (b, sq))
-    out = ulppack_attention.fused_decode_attention(
-        q, read_cache, valid_len, positions, kv_bits=kv_bits, hd=hd,
-        block_tables=block_tables, shard_axis=kv_shard_axis)
-    out = dense_apply(p["o"], out.reshape(b, sq, h * hd), **qm)
+    with jax.named_scope("core"):
+        if positions.ndim == 1:
+            positions = jnp.broadcast_to(positions[None, :], (b, sq))
+        out = ulppack_attention.fused_decode_attention(
+            q, read_cache, valid_len, positions, kv_bits=kv_bits, hd=hd,
+            block_tables=block_tables, shard_axis=kv_shard_axis)
+    with jax.named_scope("out"):
+        out = dense_apply(p["o"], out.reshape(b, sq, h * hd), **qm)
     return out, new_cache
 
 
@@ -293,15 +295,17 @@ def _attention_epilogue(p, cfg, q, kv_fn, mask_fn, positions, q_chunk,
     """Shared attention tail: positions broadcast, autotuned q-chunk
     lookup, the q-chunked softmax, and the output projection."""
     b, sq, h, hd = q.shape
-    if positions.ndim == 1:
-        positions = jnp.broadcast_to(positions[None, :], (b, sq))
-    if q_chunk is None:
-        from repro.kernels import autotune  # trace-time lookup, static ints
-        q_chunk = autotune.attention_chunk_for(
-            b, sq, int(skv), cfg.num_heads, cfg.num_kv_heads, hd,
-            int(kv_bits))
-    out = _chunked_attention(q, kv_fn, mask_fn, positions, q_chunk)
-    out = dense_apply(p["o"], out.reshape(b, sq, h * hd), **qm)
+    with jax.named_scope("core"):
+        if positions.ndim == 1:
+            positions = jnp.broadcast_to(positions[None, :], (b, sq))
+        if q_chunk is None:
+            from repro.kernels import autotune  # trace-time lookup
+            q_chunk = autotune.attention_chunk_for(
+                b, sq, int(skv), cfg.num_heads, cfg.num_kv_heads, hd,
+                int(kv_bits))
+        out = _chunked_attention(q, kv_fn, mask_fn, positions, q_chunk)
+    with jax.named_scope("out"):
+        out = dense_apply(p["o"], out.reshape(b, sq, h * hd), **qm)
     return out, new_cache
 
 
@@ -338,32 +342,37 @@ def attention_apply(p, cfg, x, *, positions, quant_mode="none",
         mask admits hold values identical to the unpaged ring, and masked
         rows contribute exactly-zero probability).  Vector cache_index
         only; sliding-window archs stay unpaged (DESIGN.md §18).
+
+    Device scopes (``jax.named_scope``): ``qkv`` (projections, RoPE),
+    ``kv_write`` (quantize, pack and write into the cache or pool),
+    ``core`` (the attention read) and ``out`` (the output projection).
     """
     b, sq, _ = x.shape
     hd = cfg.resolved_head_dim
     cd = common.dtype_of(cfg.compute_dtype)
     qm = dict(qcfg=cfg.quant, quant_mode=quant_mode, compute_dtype=cd)
 
-    q = dense_apply(p["q"], x, **qm).reshape(b, sq, cfg.num_heads, hd)
-    if cross_kv is not None:
-        k, v = cross_kv
-        kv_x = True  # marks cross-attention masking below
-    else:
-        kv_in = kv_x if kv_x is not None else x
-        k = dense_apply(p["k"], kv_in, **qm).reshape(b, -1,
-                                                     cfg.num_kv_heads, hd)
-        v = dense_apply(p["v"], kv_in, **qm).reshape(b, -1,
-                                                     cfg.num_kv_heads, hd)
-
-    if kv_x is None:  # self-attention: rotate q and k
-        if cfg.mrope and positions3 is not None:
-            q = common.apply_mrope(q, positions3, cfg.mrope_sections,
-                                   cfg.rope_theta)
-            k = common.apply_mrope(k, positions3, cfg.mrope_sections,
-                                   cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q = dense_apply(p["q"], x, **qm).reshape(b, sq, cfg.num_heads, hd)
+        if cross_kv is not None:
+            k, v = cross_kv
+            kv_x = True  # marks cross-attention masking below
         else:
-            q = common.apply_rope(q, positions, cfg.rope_theta)
-            k = common.apply_rope(k, positions, cfg.rope_theta)
+            kv_in = kv_x if kv_x is not None else x
+            k = dense_apply(p["k"], kv_in, **qm).reshape(
+                b, -1, cfg.num_kv_heads, hd)
+            v = dense_apply(p["v"], kv_in, **qm).reshape(
+                b, -1, cfg.num_kv_heads, hd)
+
+        if kv_x is None:  # self-attention: rotate q and k
+            if cfg.mrope and positions3 is not None:
+                q = common.apply_mrope(q, positions3, cfg.mrope_sections,
+                                       cfg.rope_theta)
+                k = common.apply_mrope(k, positions3, cfg.mrope_sections,
+                                       cfg.rope_theta)
+            else:
+                q = common.apply_rope(q, positions, cfg.rope_theta)
+                k = common.apply_rope(k, positions, cfg.rope_theta)
 
     window = cfg.sliding_window
     kv_bits = getattr(cfg.quant, "kv_bits", 0)
@@ -393,11 +402,12 @@ def attention_apply(p, cfg, x, *, positions, quant_mode="none",
                     else jnp.asarray(cache_valid, jnp.int32))
             offs = jnp.arange(sq, dtype=jnp.int32)
             wpos = idx[:, None] + offs[None, :]                # [B, sq]
-            page_idx = jnp.clip(wpos // page_rows, 0, bt.shape[1] - 1)
-            phys = jnp.take_along_axis(bt, page_idx, axis=1)
-            new_cache = _cache_write_paged(
-                cache, k, v, phys, wpos % page_rows,
-                offs[None, :] < vlen[:, None], kv_bits)
+            with jax.named_scope("kv_write"):
+                page_idx = jnp.clip(wpos // page_rows, 0, bt.shape[1] - 1)
+                phys = jnp.take_along_axis(bt, page_idx, axis=1)
+                new_cache = _cache_write_paged(
+                    cache, k, v, phys, wpos % page_rows,
+                    offs[None, :] < vlen[:, None], kv_bits)
             # logical row j of the gathered view holds absolute position
             # j by construction (page j // page_rows, row j % page_rows),
             # so the unpaged no-window position map applies verbatim
@@ -428,7 +438,8 @@ def attention_apply(p, cfg, x, *, positions, quant_mode="none",
         if idx.ndim == 0:
             # lockstep scalar path: every row writes the same slot
             slot = idx % size if window else idx
-            new_cache = _cache_write(cache, k, v, slot, kv_bits)
+            with jax.named_scope("kv_write"):
+                new_cache = _cache_write(cache, k, v, slot, kv_bits)
             kv_pos = _ring_positions(idx, size, window)        # [size]
         else:
             # per-slot positions: row b writes its window at absolute
@@ -446,8 +457,10 @@ def attention_apply(p, cfg, x, *, positions, quant_mode="none",
             offs = jnp.arange(sq, dtype=jnp.int32)
             wpos = idx[:, None] + offs[None, :]                # [B, sq]
             slots = wpos % size if window else wpos
-            new_cache = _cache_write_ragged(
-                cache, k, v, slots, offs[None, :] < vlen[:, None], kv_bits)
+            with jax.named_scope("kv_write"):
+                new_cache = _cache_write_ragged(
+                    cache, k, v, slots, offs[None, :] < vlen[:, None],
+                    kv_bits)
             kv_pos = _ring_positions_batch(idx + vlen - 1, size,
                                            window)            # [B, size]
         new_cache = _constrain_kv_heads(new_cache, kv_shard_axis)
@@ -483,11 +496,13 @@ def attention_apply(p, cfg, x, *, positions, quant_mode="none",
             # decode does — the fused read, or the legacy dequantizing
             # read under the REPRO_FUSED_DECODE=0 kill-switch
             stored = {}
-            stored["k"], stored["k_scale"] = _kv_quantize(k, kv_bits)
-            stored["v"], stored["v_scale"] = _kv_quantize(v, kv_bits)
-            if cache is not None:
-                new_cache = _constrain_kv_heads(
-                    _cache_write(cache, k, v, 0, kv_bits), kv_shard_axis)
+            with jax.named_scope("kv_write"):
+                stored["k"], stored["k_scale"] = _kv_quantize(k, kv_bits)
+                stored["v"], stored["v_scale"] = _kv_quantize(v, kv_bits)
+                if cache is not None:
+                    new_cache = _constrain_kv_heads(
+                        _cache_write(cache, k, v, 0, kv_bits),
+                        kv_shard_axis)
             from repro.kernels import ulppack_attention
             if ulppack_attention.enabled():
                 causal_idx = jnp.broadcast_to(jnp.arange(sq)[None, :],
@@ -498,16 +513,18 @@ def attention_apply(p, cfg, x, *, positions, quant_mode="none",
             kv_fn = lambda: _cache_read(stored, k.dtype, kv_bits, hd)
         if cache is not None and stored is None:  # prefill fills the cache
             size = cache["k"].shape[1]
-            if window and sq > size:
-                # ring layout: slot = pos % size for the last `size` tokens
-                roll = (sq % size)
-                new_cache = _cache_write(cache, k[:, -size:], v[:, -size:],
-                                         0, kv_bits)
-                new_cache = {kk: jnp.roll(vv, roll, axis=1)
-                             for kk, vv in new_cache.items()}
-            else:
-                new_cache = _cache_write(cache, k, v, 0, kv_bits)
-            new_cache = _constrain_kv_heads(new_cache, kv_shard_axis)
+            with jax.named_scope("kv_write"):
+                if window and sq > size:
+                    # ring layout: slot = pos % size for the last `size`
+                    # tokens
+                    roll = (sq % size)
+                    new_cache = _cache_write(cache, k[:, -size:],
+                                             v[:, -size:], 0, kv_bits)
+                    new_cache = {kk: jnp.roll(vv, roll, axis=1)
+                                 for kk, vv in new_cache.items()}
+                else:
+                    new_cache = _cache_write(cache, k, v, 0, kv_bits)
+                new_cache = _constrain_kv_heads(new_cache, kv_shard_axis)
         if kv_x is not None:
             kv_pos = (kv_positions if kv_positions is not None
                       else jnp.arange(k.shape[1]))[None, :]

@@ -65,59 +65,62 @@ def block_apply(p, cfg, x, *, kind="attn", positions, quant_mode="none",
     axis over (DESIGN.md §15); None = unsharded serving.  ``block_tables``
     [B, n_pages] selects the paged attention cache path (pool + per-slot
     block table, DESIGN.md §18); recurrent sub-caches stay per-slot.
+
+    Each half runs under a ``jax.named_scope`` named by its kind (``attn``,
+    ``mamba``, ``mlstm``, ``slstm``, ``cross``, ``mlp``, ``moe``), so a
+    profiler trace attributes every device op to the half that emits it.
     """
     aux = 0.0
     new_cache = dict(cache) if cache is not None else None
-    h = common.rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
-    if kind == "attn":
-        sub = cache.get("attn") if cache else None
-        out, sub2 = attention.attention_apply(
-            p["attn"], cfg, h, positions=positions, quant_mode=quant_mode,
-            cache=sub, cache_index=cache_index, cache_valid=cache_valid,
-            causal=causal, positions3=positions3,
-            kv_shard_axis=kv_shard_axis, block_tables=block_tables)
+    with jax.named_scope(kind):
+        h = common.rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
+        if kind == "attn":
+            sub = cache.get("attn") if cache else None
+            out, sub2 = attention.attention_apply(
+                p["attn"], cfg, h, positions=positions,
+                quant_mode=quant_mode, cache=sub, cache_index=cache_index,
+                cache_valid=cache_valid, causal=causal,
+                positions3=positions3, kv_shard_axis=kv_shard_axis,
+                block_tables=block_tables)
+        elif kind == "mamba":
+            sub = cache.get("mamba") if cache else None
+            out, sub2 = mamba.mamba_apply(
+                p["mamba"], cfg, h, quant_mode=quant_mode, cache=sub,
+                cache_index=cache_index, cache_valid=cache_valid)
+        elif kind == "mlstm":
+            sub = cache.get("mlstm") if cache else None
+            out, sub2 = xlstm.mlstm_apply(
+                p["mlstm"], cfg, h, quant_mode=quant_mode, cache=sub,
+                cache_index=cache_index, cache_valid=cache_valid)
+        elif kind == "slstm":
+            sub = cache.get("slstm") if cache else None
+            out, sub2 = xlstm.slstm_apply(
+                p["slstm"], cfg, h, quant_mode=quant_mode, cache=sub,
+                cache_index=cache_index, cache_valid=cache_valid)
+        else:
+            raise ValueError(kind)
         if new_cache is not None and sub2 is not None:
-            new_cache["attn"] = sub2
-    elif kind == "mamba":
-        sub = cache.get("mamba") if cache else None
-        out, sub2 = mamba.mamba_apply(
-            p["mamba"], cfg, h, quant_mode=quant_mode, cache=sub,
-            cache_index=cache_index, cache_valid=cache_valid)
-        if new_cache is not None and sub2 is not None:
-            new_cache["mamba"] = sub2
-    elif kind == "mlstm":
-        sub = cache.get("mlstm") if cache else None
-        out, sub2 = xlstm.mlstm_apply(
-            p["mlstm"], cfg, h, quant_mode=quant_mode, cache=sub,
-            cache_index=cache_index, cache_valid=cache_valid)
-        if new_cache is not None and sub2 is not None:
-            new_cache["mlstm"] = sub2
-    elif kind == "slstm":
-        sub = cache.get("slstm") if cache else None
-        out, sub2 = xlstm.slstm_apply(
-            p["slstm"], cfg, h, quant_mode=quant_mode, cache=sub,
-            cache_index=cache_index, cache_valid=cache_valid)
-        if new_cache is not None and sub2 is not None:
-            new_cache["slstm"] = sub2
-    else:
-        raise ValueError(kind)
-    x = x + out
+            new_cache[kind] = sub2
+        x = x + out
 
     if "cross" in p and enc_kv is not None:
-        h = common.rmsnorm_apply(p["norm_x"], x, cfg.norm_eps)
-        out, _ = attention.attention_apply(
-            p["cross"], cfg, h, positions=positions, quant_mode=quant_mode,
-            cross_kv=enc_kv, causal=False)
-        x = x + out
+        with jax.named_scope("cross"):
+            h = common.rmsnorm_apply(p["norm_x"], x, cfg.norm_eps)
+            out, _ = attention.attention_apply(
+                p["cross"], cfg, h, positions=positions,
+                quant_mode=quant_mode, cross_kv=enc_kv, causal=False)
+            x = x + out
 
     if "moe" in p:
-        h = common.rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
-        out, aux = moe.moe_apply(p["moe"], cfg, h, quant_mode=quant_mode,
-                                 path=moe_path)
-        x = x + out
+        with jax.named_scope("moe"):
+            h = common.rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
+            out, aux = moe.moe_apply(p["moe"], cfg, h,
+                                     quant_mode=quant_mode, path=moe_path)
+            x = x + out
     elif "mlp" in p:
-        h = common.rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
-        x = x + mlp.mlp_apply(p["mlp"], cfg, h, quant_mode=quant_mode)
+        with jax.named_scope("mlp"):
+            h = common.rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
+            x = x + mlp.mlp_apply(p["mlp"], cfg, h, quant_mode=quant_mode)
     return x, new_cache, aux
 
 
@@ -196,12 +199,17 @@ def forward(params, cfg, batch, *, quant_mode="none", caches=None,
     the cache between steps.  ``block_tables`` [B, n_pages] routes every
     attention layer through the paged cache pool (DESIGN.md §18); the one
     table indexes all layers' pools.
+
+    Device scopes (``jax.named_scope``): ``embed``, ``layer_<i>`` per block
+    (block_apply names its halves), ``head`` for the final norm, the output
+    head and the pad bias.
     """
     import os
     seq_ax = "model" if os.environ.get("REPRO_SEQ_ACT", "0") == "1" \
         else None
-    x, positions = _decoder_inputs(params, cfg, batch)
-    x = constrain(x, "dp", seq_ax, None)
+    with jax.named_scope("embed"):
+        x, positions = _decoder_inputs(params, cfg, batch)
+        x = constrain(x, "dp", seq_ax, None)
     positions3 = batch.get("positions3")
 
     enc_kv = None
@@ -233,12 +241,13 @@ def forward(params, cfg, batch, *, quant_mode="none", caches=None,
         sub = caches[li] if caches is not None else None
         fn = jax.checkpoint(run_block, static_argnums=(3,)) if remat \
             else run_block
-        x, sub2, aux = fn(blk, x, sub, cfg.layer_kind(li))
-        # Megatron-SP (REPRO_SEQ_ACT=1): residual stream sequence-sharded
-        # over the TP axis between blocks -> the TP all-reduce becomes a
-        # reduce-scatter + all-gather pair (half the wire bytes) and norms
-        # run seq-sharded (§Perf cell B)
-        x = constrain(x, "dp", seq_ax, None)
+        with jax.named_scope(f"layer_{li}"):
+            x, sub2, aux = fn(blk, x, sub, cfg.layer_kind(li))
+            # Megatron-SP (REPRO_SEQ_ACT=1): residual stream sequence-
+            # sharded over the TP axis between blocks -> the TP all-reduce
+            # becomes a reduce-scatter + all-gather pair (half the wire
+            # bytes) and norms run seq-sharded (§Perf cell B)
+            x = constrain(x, "dp", seq_ax, None)
         aux_total = aux_total + aux
         if new_caches is not None:
             if cfg.is_encoder_decoder and enc_kv is not None:
@@ -246,23 +255,24 @@ def forward(params, cfg, batch, *, quant_mode="none", caches=None,
                 sub2["cross_kv"] = enc_kv
             new_caches.append(sub2)
 
-    x = common.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = common.embedding_attend(params["embed"], x)
-    else:
-        logits = dense_apply(
-            params["lm_head"], x,
-            qcfg=cfg.quant if cfg.quant.quantize_lm_head else None,
-            quant_mode=quant_mode,
-            compute_dtype=common.dtype_of(cfg.compute_dtype))
-    logits = constrain(logits, "dp", None, "model")
-    if cfg.padded_vocab != cfg.vocab_size:
-        # additive pad bias (fuses into the head matmul epilogue) instead of
-        # a where() over an f32 copy — §Perf cell-A iteration 4
-        pad_bias = jnp.where(
-            jnp.arange(cfg.padded_vocab) >= cfg.vocab_size, -1e30,
-            0.0).astype(logits.dtype)
-        logits = logits + pad_bias
+    with jax.named_scope("head"):
+        x = common.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = common.embedding_attend(params["embed"], x)
+        else:
+            logits = dense_apply(
+                params["lm_head"], x,
+                qcfg=cfg.quant if cfg.quant.quantize_lm_head else None,
+                quant_mode=quant_mode,
+                compute_dtype=common.dtype_of(cfg.compute_dtype))
+        logits = constrain(logits, "dp", None, "model")
+        if cfg.padded_vocab != cfg.vocab_size:
+            # additive pad bias (fuses into the head matmul epilogue)
+            # instead of a where() over an f32 copy — §Perf cell-A iter. 4
+            pad_bias = jnp.where(
+                jnp.arange(cfg.padded_vocab) >= cfg.vocab_size, -1e30,
+                0.0).astype(logits.dtype)
+            logits = logits + pad_bias
     return logits, aux_total, new_caches
 
 

@@ -24,6 +24,23 @@ DESIGN.md §18): admission reserves pages instead of max_len slots, prompt
 prefixes are shared via a radix index with copy-on-write on divergence,
 and retirement frees pages — the HBM budget then bounds *physical* pages
 while ``max_batch`` bounds *logical* slots.
+
+Every tick is traced on the profiler's clock (``jax.profiler``
+``TraceAnnotation`` host spans, recorded only while a trace is on):
+
+    engine.step                    one scheduler tick
+      engine.admit                 admission (metadata: admitted uids)
+      engine.prefill_pass          (metadata: uids consuming prompt)
+        engine.batch               numpy batch, block tables, device puts
+          engine.cow               one per page copied (src, dst page)
+        engine.launch.prefill      the jitted call (dispatch only)
+        engine.logits              device-to-host copy of the logits
+        engine.draft_prefill       speculative draft's own window
+        engine.sample              sampling of every row of the pass
+      engine.decode_pass           the same, with engine.launch.decode
+      engine.speculative_pass      engine.batch, engine.launch.draft,
+                                   engine.drafted, engine.launch.verify,
+                                   engine.logits, engine.accept
 """
 
 from __future__ import annotations
@@ -36,6 +53,7 @@ from collections import deque
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as Span
 
 from repro.launch import steps as steps_lib
 from repro.models import lm
@@ -47,6 +65,12 @@ from repro.serve.prepare import (build_layer_plans, cache_bytes_per_slot,
 
 __all__ = ["EngineConfig", "Metrics", "Request", "SamplingParams",
            "ServingEngine"]
+
+
+def _uids(span, reqs):
+    """Name the requests a span served, only while a trace records."""
+    if Span.is_enabled():
+        span.set_metadata(uids=" ".join(str(r.uid) for r in reqs))
 
 
 @dataclasses.dataclass
@@ -460,13 +484,18 @@ class ServingEngine:
                         f"slot {slot} (page {pg}); reservation math must "
                         f"cover every divergence write")
                 dst = got[0]
-            self.caches = pages_lib.copy_page(self.caches, pg, dst)
+            with Span("engine.cow") as span:
+                if Span.is_enabled():
+                    span.set_metadata(src=pg, dst=int(dst))
+                self.caches = pages_lib.copy_page(self.caches, pg, dst)
             table[pi] = dst
             self.pool.release(pg)
             self.pool.cow_copies += 1
 
-    def _admit(self):
+    def _admit(self) -> list:
+        """Move queued requests into free slots; returns those admitted."""
         now = time.perf_counter()
+        admitted = []
         for slot in range(self.max_batch):
             if self.slot_req[slot] is None and self._queue:
                 req = self._queue[0]
@@ -493,6 +522,8 @@ class ServingEngine:
                 req.admit_time = now
                 self.metrics.admitted += 1
                 self.metrics.admission_wait_s += now - req.submit_time
+                admitted.append(req)
+        return admitted
 
     # ------------------------------------------------------------------
     # Stepping
@@ -503,41 +534,43 @@ class ServingEngine:
         chunked prefill while any slot is mid-prompt (followed by a decode
         launch for the decode-phase slots), else a single-token ragged
         decode."""
-        self._admit()
-        live = [s for s in range(self.max_batch)
-                if self.slot_req[s] is not None]
-        if not live:
-            return False
-        self.metrics.steps += 1
-        self.metrics.slot_steps_live += len(live)
-        self.metrics.slot_steps_total += self.max_batch
-        if self.paged:
-            self.peak_live_slots = max(self.peak_live_slots, len(live))
-        prefilling = any(
-            self.slot_fed[s] < len(self.slot_req[s].prompt) for s in live)
-        if self.spec is not None:
-            # the draft may still be replaying a prefix-skipped prompt
-            # after the target finished; keep the pass a prefill pass
-            # (speculation only runs on pure-decode passes)
-            prefilling = prefilling or any(
-                not self.spec.prompt_done(s, self.slot_req[s])
-                for s in live)
-        t0 = time.perf_counter()
-        if prefilling:
-            n_prompt, decoding = self._prefill_pass(live)
-            t1 = time.perf_counter()
-            self.metrics.prefill_time_s += t1 - t0
-            self.metrics.prefill_tokens += n_prompt
-            if decoding:
-                self._decode_pass(decoding)
-                self.metrics.decode_time_s += time.perf_counter() - t1
-        elif self.spec is not None:
-            self._speculative_pass(live)
-            self.metrics.decode_time_s += time.perf_counter() - t0
-        else:
-            self._decode_pass(live)
-            self.metrics.decode_time_s += time.perf_counter() - t0
-        return True
+        with Span("engine.step"):
+            with Span("engine.admit") as span:
+                _uids(span, self._admit())
+            live = [s for s in range(self.max_batch)
+                    if self.slot_req[s] is not None]
+            if not live:
+                return False
+            self.metrics.steps += 1
+            self.metrics.slot_steps_live += len(live)
+            self.metrics.slot_steps_total += self.max_batch
+            if self.paged:
+                self.peak_live_slots = max(self.peak_live_slots, len(live))
+            prefilling = any(
+                self.slot_fed[s] < len(self.slot_req[s].prompt) for s in live)
+            if self.spec is not None:
+                # the draft may still be replaying a prefix-skipped prompt
+                # after the target finished; keep the pass a prefill pass
+                # (speculation only runs on pure-decode passes)
+                prefilling = prefilling or any(
+                    not self.spec.prompt_done(s, self.slot_req[s])
+                    for s in live)
+            t0 = time.perf_counter()
+            if prefilling:
+                n_prompt, decoding = self._prefill_pass(live)
+                t1 = time.perf_counter()
+                self.metrics.prefill_time_s += t1 - t0
+                self.metrics.prefill_tokens += n_prompt
+                if decoding:
+                    self._decode_pass(decoding)
+                    self.metrics.decode_time_s += time.perf_counter() - t1
+            elif self.spec is not None:
+                self._speculative_pass(live)
+                self.metrics.decode_time_s += time.perf_counter() - t0
+            else:
+                self._decode_pass(live)
+                self.metrics.decode_time_s += time.perf_counter() - t0
+            return True
 
     def _positions3(self, index: np.ndarray, width: int):
         pos = index[:, None] + np.arange(width, dtype=np.int32)[None, :]
@@ -551,46 +584,58 @@ class ServingEngine:
         after: a row's numbers then never depend on which program it rode
         in, so a request gets the same tokens whatever it is batched with
         (DESIGN.md §12)."""
-        c = self.prefill_chunk
-        tokens = np.zeros((self.max_batch, c), np.int32)
-        index = np.zeros(self.max_batch, np.int32)
-        valid = np.zeros(self.max_batch, np.int32)
-        take = {}
-        decoding = []
-        n_prompt = 0
-        for s in live:
-            req = self.slot_req[s]
-            index[s] = self.slot_pos[s]
-            rem = len(req.prompt) - int(self.slot_fed[s])
-            if rem > 0:        # mid-prompt: its next chunk window
-                t = min(c, rem)
-                fed = int(self.slot_fed[s])
-                tokens[s, :t] = req.prompt[fed:fed + t]
-                valid[s] = take[s] = t
-                n_prompt += t
-            elif req.output:   # decode phase: steps in the decode program
-                decoding.append(s)
-            # else: target prompt done but the first token is stashed
-            # until the speculative draft finishes its full-prompt
-            # replay — a dead slot (valid 0) in this target pass
-        batch = {"tokens": jnp.asarray(tokens)}
-        if self.cfg.mrope:
-            batch["positions3"] = self._positions3(index, c)
-        step_args = ()
-        if self.paged:
-            for s in live:
-                lo = int(index[s])
-                self._ensure_writable(s, lo, lo + int(valid[s]))
-            step_args = (jnp.asarray(self.block_tables),)
-        logits = None
-        if int(valid.sum()):   # all-stash-waiting passes skip the launch
-            with self._mesh_ctx():
-                logits, self.caches = self._prefill(
-                    self.params, self.caches, batch, jnp.asarray(index),
-                    jnp.asarray(valid), *step_args)
-            logits = np.asarray(logits)
-        if self.spec is not None:
-            self._draft_prefill(live)
+        with Span("engine.prefill_pass") as span:
+            with Span("engine.batch"):
+                c = self.prefill_chunk
+                tokens = np.zeros((self.max_batch, c), np.int32)
+                index = np.zeros(self.max_batch, np.int32)
+                valid = np.zeros(self.max_batch, np.int32)
+                take = {}
+                decoding = []
+                n_prompt = 0
+                for s in live:
+                    req = self.slot_req[s]
+                    index[s] = self.slot_pos[s]
+                    rem = len(req.prompt) - int(self.slot_fed[s])
+                    if rem > 0:        # mid-prompt: its next chunk window
+                        t = min(c, rem)
+                        fed = int(self.slot_fed[s])
+                        tokens[s, :t] = req.prompt[fed:fed + t]
+                        valid[s] = take[s] = t
+                        n_prompt += t
+                    elif req.output:   # decode phase: the decode program
+                        decoding.append(s)
+                    # else: target prompt done but the first token is
+                    # stashed until the speculative draft finishes its
+                    # full-prompt replay — a dead slot (valid 0) here
+                batch = {"tokens": jnp.asarray(tokens)}
+                if self.cfg.mrope:
+                    batch["positions3"] = self._positions3(index, c)
+                step_args = ()
+                if self.paged:
+                    for s in live:
+                        lo = int(index[s])
+                        self._ensure_writable(s, lo, lo + int(valid[s]))
+                    step_args = (jnp.asarray(self.block_tables),)
+                dev_index, dev_valid = jnp.asarray(index), jnp.asarray(valid)
+            _uids(span, (self.slot_req[s] for s in take))
+            logits = None
+            if int(valid.sum()):   # all-stash-waiting passes skip it
+                with Span("engine.launch.prefill"), self._mesh_ctx():
+                    logits, self.caches = self._prefill(
+                        self.params, self.caches, batch, dev_index,
+                        dev_valid, *step_args)
+                with Span("engine.logits"):
+                    logits = np.asarray(logits)
+            if self.spec is not None:
+                self._draft_prefill(live)
+            with Span("engine.sample"):
+                self._sample_prefill(live, take, logits)
+        return n_prompt, decoding
+
+    def _sample_prefill(self, live, take, logits):
+        """Advance each prefilled slot; a slot whose prompt just completed
+        samples its first token (or parks its logits for the draft)."""
         for s in live:
             req = self.slot_req[s]
             if s in take:
@@ -611,7 +656,6 @@ class ServingEngine:
                 # the draft just caught up: emit the parked first token
                 self._emit_token(s, self.spec.pop_stash(s),
                                  decode_pass=False)
-        return n_prompt, decoding
 
     def _draft_prefill(self, live):
         """Feed the speculative draft cache its own prefill window:
@@ -620,33 +664,37 @@ class ServingEngine:
         single pending token for decode riders so draft and target
         caches stay position-aligned through mixed passes."""
         spec = self.spec
-        c = self.prefill_chunk
-        tokens = np.zeros((self.max_batch, c), np.int32)
-        index = np.zeros(self.max_batch, np.int32)
-        valid = np.zeros(self.max_batch, np.int32)
-        fed_take = {}
-        for s in live:
-            req = self.slot_req[s]
-            fed = int(spec.fed[s])
-            rem = len(req.prompt) - fed
-            if rem > 0:
-                t = min(c, rem)
-                tokens[s, :t] = req.prompt[fed:fed + t]
-                index[s] = fed
-                valid[s] = fed_take[s] = t
-            elif req.output:
-                tokens[s, 0] = req.output[-1]
-                index[s] = self.slot_pos[s]
-                valid[s] = 1
-        if not int(valid.sum()):
-            return
-        step_args = (jnp.asarray(spec.block_tables),) if spec.paged else ()
-        with self._mesh_ctx():
-            _, spec.caches = spec._prefill(
-                spec.params, spec.caches, {"tokens": jnp.asarray(tokens)},
-                jnp.asarray(index), jnp.asarray(valid), *step_args)
-        for s, t in fed_take.items():
-            spec.fed[s] += t
+        with Span("engine.draft_prefill"):
+            with Span("engine.batch"):
+                c = self.prefill_chunk
+                tokens = np.zeros((self.max_batch, c), np.int32)
+                index = np.zeros(self.max_batch, np.int32)
+                valid = np.zeros(self.max_batch, np.int32)
+                fed_take = {}
+                for s in live:
+                    req = self.slot_req[s]
+                    fed = int(spec.fed[s])
+                    rem = len(req.prompt) - fed
+                    if rem > 0:
+                        t = min(c, rem)
+                        tokens[s, :t] = req.prompt[fed:fed + t]
+                        index[s] = fed
+                        valid[s] = fed_take[s] = t
+                    elif req.output:
+                        tokens[s, 0] = req.output[-1]
+                        index[s] = self.slot_pos[s]
+                        valid[s] = 1
+                if not int(valid.sum()):
+                    return
+                step_args = (jnp.asarray(spec.block_tables),) \
+                    if spec.paged else ()
+                args = ({"tokens": jnp.asarray(tokens)}, jnp.asarray(index),
+                        jnp.asarray(valid), *step_args)
+            with Span("engine.launch.draft_prefill"), self._mesh_ctx():
+                _, spec.caches = spec._prefill(spec.params, spec.caches,
+                                               *args)
+            for s, t in fed_take.items():
+                spec.fed[s] += t
 
     def _register_prompt(self, s: int, req: Request):
         """Hash-cons the just-completed prompt's pages into the prefix
@@ -658,31 +706,38 @@ class ServingEngine:
             req.prompt, [int(p) for p in self.block_tables[s][:n_pages]])
 
     def _decode_pass(self, live):
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        index = np.zeros(self.max_batch, np.int32)
-        valid = np.zeros(self.max_batch, np.int32)
-        for s in live:
-            req = self.slot_req[s]
-            tokens[s, 0] = req.output[-1] if req.output \
-                else int(req.prompt[-1])
-            index[s] = self.slot_pos[s]
-            valid[s] = 1
-        batch = {"tokens": jnp.asarray(tokens)}
-        if self.cfg.mrope:
-            batch["positions3"] = self._positions3(index, 1)
-        step_args = ()
-        if self.paged:
-            for s in live:
-                self._ensure_writable(s, int(index[s]), int(index[s]) + 1)
-            step_args = (jnp.asarray(self.block_tables),)
-        with self._mesh_ctx():
-            logits, self.caches = self._decode(
-                self.params, self.caches, batch, jnp.asarray(index),
-                jnp.asarray(valid), *step_args)
-        logits = np.asarray(logits)
-        for s in live:
-            self.slot_pos[s] += 1
-            self._emit_token(s, logits[s], decode_pass=True)
+        with Span("engine.decode_pass") as span:
+            _uids(span, (self.slot_req[s] for s in live))
+            with Span("engine.batch"):
+                tokens = np.zeros((self.max_batch, 1), np.int32)
+                index = np.zeros(self.max_batch, np.int32)
+                valid = np.zeros(self.max_batch, np.int32)
+                for s in live:
+                    req = self.slot_req[s]
+                    tokens[s, 0] = req.output[-1] if req.output \
+                        else int(req.prompt[-1])
+                    index[s] = self.slot_pos[s]
+                    valid[s] = 1
+                batch = {"tokens": jnp.asarray(tokens)}
+                if self.cfg.mrope:
+                    batch["positions3"] = self._positions3(index, 1)
+                step_args = ()
+                if self.paged:
+                    for s in live:
+                        self._ensure_writable(s, int(index[s]),
+                                              int(index[s]) + 1)
+                    step_args = (jnp.asarray(self.block_tables),)
+                dev_index, dev_valid = jnp.asarray(index), jnp.asarray(valid)
+            with Span("engine.launch.decode"), self._mesh_ctx():
+                logits, self.caches = self._decode(
+                    self.params, self.caches, batch, dev_index, dev_valid,
+                    *step_args)
+            with Span("engine.logits"):
+                logits = np.asarray(logits)
+            with Span("engine.sample"):
+                for s in live:
+                    self.slot_pos[s] += 1
+                    self._emit_token(s, logits[s], decode_pass=True)
 
     def _speculative_pass(self, live):
         """One speculative cycle (DESIGN.md §19): draft up to ``k``
@@ -691,59 +746,68 @@ class ServingEngine:
         prefill-chunk window shape), then commit the longest
         target-faithful prefix per slot via rejection sampling
         (speculative.accept_tokens) — 1..k+1 tokens for two launches."""
-        k = self.config.speculative_k
-        spec = self.spec
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        index = np.zeros(self.max_batch, np.int32)
-        # dead slots draft at limit -1: limit+1 = 0 gates off every cache
-        # write (a paged dead slot's block table row would alias page 0)
-        limit = np.full(self.max_batch, -1, np.int32)
-        for s in live:
-            req = self.slot_req[s]
-            tokens[s, 0] = req.output[-1] if req.output \
-                else int(req.prompt[-1])
-            index[s] = self.slot_pos[s]
-            # a cycle commits at most limit+1 tokens, so limit =
-            # min(k, remaining-1) never drafts past the request budget
-            # and every cache write stays inside the reserved extent
-            limit[s] = min(k, req.max_new_tokens - len(req.output) - 1)
-        batch = {"tokens": jnp.asarray(tokens)}
-        d_args = (jnp.asarray(spec.block_tables),) if spec.paged else ()
-        with self._mesh_ctx():
-            drafted, spec.caches = spec._draft(
-                spec.params, spec.caches, batch, jnp.asarray(index),
-                jnp.asarray(limit), *d_args)
-        drafted = np.asarray(drafted)                      # [B, k]
-        win = np.zeros((self.max_batch, k + 1), np.int32)  # [t0, d_0..]
-        win[:, 0] = tokens[:, 0]
-        win[:, 1:] = drafted
-        valid = np.maximum(limit + 1, 0)
-        v_args = ()
-        if self.paged:
-            for s in live:
-                lo = int(index[s])
-                self._ensure_writable(s, lo, lo + int(valid[s]))
-            v_args = (jnp.asarray(self.block_tables),)
-        with self._mesh_ctx():
-            logits, self.caches = self._verify(
-                self.params, self.caches, {"tokens": jnp.asarray(win)},
-                jnp.asarray(index), jnp.asarray(valid), *v_args)
-        logits = np.asarray(logits)                        # [B, k+1, V]
-        self.metrics.spec_cycles += 1
-        for s in live:
-            req = self.slot_req[s]
-            lim = int(limit[s])
-            committed = speculative_lib.accept_tokens(
-                logits[s, :lim + 1], drafted[s, :lim],
-                req.sampling or self.sampling, self._slot_rng[s])
-            self.metrics.drafted_tokens += lim
-            self.metrics.accepted_tokens += len(committed) - 1
-            self.metrics.verify_tokens += lim + 1
-            for tok in committed:
-                self.slot_pos[s] += 1
-                self._commit_token(s, int(tok), decode_pass=True)
-                if self.slot_req[s] is None:   # retired mid-window
-                    break
+        with Span("engine.speculative_pass") as span:
+            _uids(span, (self.slot_req[s] for s in live))
+            k = self.config.speculative_k
+            spec = self.spec
+            with Span("engine.batch"):
+                tokens = np.zeros((self.max_batch, 1), np.int32)
+                index = np.zeros(self.max_batch, np.int32)
+                # dead slots draft at limit -1: limit+1 = 0 gates off every
+                # cache write (a paged dead slot's block table row would alias
+                # page 0)
+                limit = np.full(self.max_batch, -1, np.int32)
+                for s in live:
+                    req = self.slot_req[s]
+                    tokens[s, 0] = req.output[-1] if req.output \
+                        else int(req.prompt[-1])
+                    index[s] = self.slot_pos[s]
+                    # a cycle commits at most limit+1 tokens, so limit =
+                    # min(k, remaining-1) never drafts past the request budget
+                    # and every cache write stays inside the reserved extent
+                    limit[s] = min(k, req.max_new_tokens - len(req.output) - 1)
+                d_args = ({"tokens": jnp.asarray(tokens)}, jnp.asarray(index),
+                          jnp.asarray(limit))
+                if spec.paged:
+                    d_args += (jnp.asarray(spec.block_tables),)
+            with Span("engine.launch.draft"), self._mesh_ctx():
+                drafted, spec.caches = spec._draft(spec.params, spec.caches,
+                                                   *d_args)
+            with Span("engine.drafted"):
+                drafted = np.asarray(drafted)                  # [B, k]
+            with Span("engine.batch"):
+                win = np.zeros((self.max_batch, k + 1), np.int32)  # [t0, d..]
+                win[:, 0] = tokens[:, 0]
+                win[:, 1:] = drafted
+                valid = np.maximum(limit + 1, 0)
+                v_args = ({"tokens": jnp.asarray(win)}, jnp.asarray(index),
+                          jnp.asarray(valid))
+                if self.paged:
+                    for s in live:
+                        lo = int(index[s])
+                        self._ensure_writable(s, lo, lo + int(valid[s]))
+                    v_args += (jnp.asarray(self.block_tables),)
+            with Span("engine.launch.verify"), self._mesh_ctx():
+                logits, self.caches = self._verify(self.params, self.caches,
+                                                   *v_args)
+            with Span("engine.logits"):
+                logits = np.asarray(logits)                    # [B, k+1, V]
+            self.metrics.spec_cycles += 1
+            with Span("engine.accept"):
+                for s in live:
+                    req = self.slot_req[s]
+                    lim = int(limit[s])
+                    committed = speculative_lib.accept_tokens(
+                        logits[s, :lim + 1], drafted[s, :lim],
+                        req.sampling or self.sampling, self._slot_rng[s])
+                    self.metrics.drafted_tokens += lim
+                    self.metrics.accepted_tokens += len(committed) - 1
+                    self.metrics.verify_tokens += lim + 1
+                    for tok in committed:
+                        self.slot_pos[s] += 1
+                        self._commit_token(s, int(tok), decode_pass=True)
+                        if self.slot_req[s] is None:   # retired mid-window
+                            break
 
     def _emit_token(self, s: int, logits_row: np.ndarray, *,
                     decode_pass: bool):
