@@ -375,8 +375,9 @@ def run_attention_decode(quick: bool = False):
     rows = []
     for case, paged in (("attention-decode/paged-kv2", True),
                         ("attention-decode/contiguous-kv2", False)):
-        if paged:
-            cache = quantized(b * n_pages)
+        if paged:         # the lane-flat pool, [P, ps, kvh * words]
+            cache = {kk: vv.reshape(b * n_pages, ps, -1)
+                     for kk, vv in quantized(b * n_pages).items()}
             bt = jnp.asarray(np.arange(b * n_pages).reshape(b, n_pages),
                              jnp.int32)
         else:

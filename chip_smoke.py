@@ -221,12 +221,12 @@ def kernel_phase(jax, jnp, np, cfg, seed):
     pool = b * n_pages
     for bits in (4, 2):
         words = -(-hd // (32 // bits))
-        cache = {
-            "k": jnp.asarray(rng.integers(-2**31, 2**31, (pool, ps, kvh,
-                                                           words)),
+        cache = {        # the lane-flat pool, [pool, ps, kvh * words]
+            "k": jnp.asarray(rng.integers(-2**31, 2**31, (pool, ps,
+                                                           kvh * words)),
                              jnp.int32),
-            "v": jnp.asarray(rng.integers(-2**31, 2**31, (pool, ps, kvh,
-                                                           words)),
+            "v": jnp.asarray(rng.integers(-2**31, 2**31, (pool, ps,
+                                                           kvh * words)),
                              jnp.int32),
             "k_scale": jnp.asarray(rng.uniform(0.02, 0.2, (pool, ps, kvh)),
                                    jnp.bfloat16),

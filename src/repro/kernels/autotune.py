@@ -838,7 +838,8 @@ def tune_attention_decode(b: int, skv: int, h: int, kvh: int, hd: int, *,
     bt = None
     if page_size:
         n_pages = skv // page_size
-        kv = {name: buf.reshape(b * n_pages, page_size, *buf.shape[2:])
+        # the lane-flat page pool (attention.init_paged_kv_cache)
+        kv = {name: buf.reshape(b * n_pages, page_size, -1)
               for name, buf in kv.items()}
         bt = jnp.asarray(np.arange(b * n_pages).reshape(b, n_pages),
                          jnp.int32)

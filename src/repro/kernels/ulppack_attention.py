@@ -86,6 +86,19 @@ def disabled():
 # Shared pieces
 # ---------------------------------------------------------------------------
 
+def pool_kv_heads(pool, hd):
+    """KV heads in a lane-flat page pool (or one shard of it; k/v
+    [P, ps, KVH * w], attention.init_paged_kv_cache): the scale plane's
+    last axis, or for a float pool the lanes over ``hd``."""
+    if "k_scale" in pool:
+        return pool["k_scale"].shape[-1]
+    return pool["k"].shape[-1] // hd
+
+
+def _kv_heads(cache, hd, paged):
+    return pool_kv_heads(cache, hd) if paged else cache["k"].shape[2]
+
+
 def _unpack_group(words, bits, hd):
     """int32 words [..., hdw] -> f32 lattice values [..., hd] (the shift/
     mask expansion of packing.unpack_words, ascending field order)."""
@@ -165,7 +178,7 @@ def _attention_decode_xla(plan, q, cache, valid_len, qpos,
     ``group_start < max(valid_len)`` so fully-dead groups cost one scalar
     compare instead of an unpack + two contractions."""
     b, c, h, _ = q.shape
-    kvh = cache["k"].shape[2]
+    kvh = _kv_heads(cache, hd, block_tables is not None)
     zp = (1 << (kv_bits - 1)) if kv_bits in (4, 2) else 0
     quantized = "k_scale" in cache
     qg, qsum = _prep_q(q, kvh)
@@ -193,12 +206,13 @@ def _attention_decode_xla(plan, q, cache, valid_len, qpos,
                 pages = block_tables[:, g0:g0 + pp]
                 span = pages.shape[1] * page_rows
 
-                def gather(buf):
-                    gg = buf[pages]
-                    return gg.reshape(b, span, *gg.shape[3:])
-                gk, gv = gather(cache["k"]), gather(cache["v"])
-                gsk = gather(cache["k_scale"]) if quantized else None
-                gsv = gather(cache["v_scale"]) if quantized else None
+                def gather(buf, *heads):
+                    # lane-flat rows split into [KVH, w] after the gather
+                    return buf[pages].reshape(b, span, *heads)
+                gk = gather(cache["k"], kvh, -1)
+                gv = gather(cache["v"], kvh, -1)
+                gsk = gather(cache["k_scale"], kvh) if quantized else None
+                gsv = gather(cache["v_scale"], kvh) if quantized else None
                 return gk, gv, gsk, gsv, span
         else:
             t0 = g0
@@ -232,8 +246,11 @@ def _attention_decode_xla(plan, q, cache, valid_len, qpos,
 # Mosaic lowers plain 2-D matmuls, not the [span, KVH, hd] head-batched
 # contractions or the in-register word reshapes of the 'xla' path, so the
 # kernel works on a lane-flat cache row: one token row is its KVH kv-head
-# words side by side, [KVH * hdw] lanes (a free reshape of the stored
-# cache).  Field j of every word is one shift-mask plane u_j [span, KVH*hdw]
+# words side by side, [KVH * hdw] lanes.  The page pool is stored that way
+# (attention.init_paged_kv_cache), so the paged kernel reads it as stored;
+# on a TPU a reshape of a 4-D [N, rows, KVH, hdw] cache into this view is
+# a relayout copy of the whole cache, which the contiguous ring still
+# pays.  Field j of every word is one shift-mask plane u_j [span, KVH*hdw]
 # holding dims w*per + j of each head.  The query side is laid out to match
 # in the wrapper: per field j, a block-diagonal [H, KVH*hdw] matrix whose
 # row (kvh, g) holds that query head's dims w*per + j in kv head kvh's
@@ -366,20 +383,20 @@ def _attention_decode_pallas(plan, q, cache, valid_len, qpos,
     b, c, h, _ = q.shape
     if c != 1:
         raise ValueError("pallas attention_decode handles sq == 1 only")
-    kvh = cache["k"].shape[2]
+    paged = block_tables is not None
+    kvh = _kv_heads(cache, hd, paged)
     groups = h // kvh
     packed = kv_bits in (4, 2)
     per = 32 // kv_bits if packed else 1
     zp = (1 << (kv_bits - 1)) if packed else 0
     quantized = "k_scale" in cache
-    hdw = cache["k"].shape[-1]
+    hdw = cache["k"].shape[-1] // kvh if paged else cache["k"].shape[-1]
     width = kvh * hdw
     qbd, qsum = _block_diag_query(q, kvh, per, hdw)
     onehot = (jnp.arange(h)[:, None] // groups
               == jnp.arange(kvh)[None, :]).astype(jnp.float32)
     vl = valid_len.astype(jnp.int32).reshape(b)
     qp = qpos[:, 0].astype(jnp.int32).reshape(b)
-    paged = block_tables is not None
 
     def flat(x):            # [N, rows, KVH, hdw] -> [N, rows, KVH*hdw]
         return x.reshape(*x.shape[:2], -1)
@@ -394,7 +411,7 @@ def _attention_decode_pallas(plan, q, cache, valid_len, qpos,
         row_map = lambda i, j, *_: (i, 0, 0)
         row4_map = lambda i, j, *_: (i, 0, 0, 0)
         const_map = lambda i, j, *_: (0, 0)
-        ks, vs = flat(cache["k"]), flat(cache["v"])
+        ks, vs = cache["k"], cache["v"]         # stored lane-flat
         scales = [cache["k_scale"], cache["v_scale"]] if quantized else []
     else:
         skv = cache["k"].shape[1]
@@ -452,8 +469,12 @@ def fused_decode_attention(q, cache, valid_len, qpos, *, kv_bits, hd,
                            shard_axis=None):
     """Flash-decoding attention over the stored (possibly packed) cache.
 
-    q [B, C, H, hd]; ``cache`` the stored layout (init_kv_cache /
-    init_paged_kv_cache); ``valid_len`` [B] live token rows per sequence
+    q [B, C, H, hd]; ``cache`` the stored layout: the ring
+    [B, S, KVH, w] (init_kv_cache) or, with ``block_tables``, the
+    lane-flat page pool [P, page_size, KVH * w] (init_paged_kv_cache),
+    whose kv heads are read off the scale plane (or, unquantized, the
+    lanes over ``hd``), never off a head axis it does not have;
+    ``valid_len`` [B] live token rows per sequence
     (logical-view prefix); ``qpos`` [B, C] absolute query positions.
     ``plan`` defaults to :func:`plan_attention_decode` for the shape;
     the 'pallas' backend serves C == 1 only (wider verify windows route
@@ -463,7 +484,7 @@ def fused_decode_attention(q, cache, valid_len, qpos, *, kv_bits, hd,
     Returns [B, C, H, hd] in q.dtype.
     """
     b, c, h, _ = q.shape
-    kvh = cache["k"].shape[2]
+    kvh = _kv_heads(cache, hd, block_tables is not None)
     backend = plan.backend if plan is not None \
         else plan_lib.resolve_backend(backend)
     if backend == "pallas" and c != 1:
@@ -481,7 +502,9 @@ def fused_decode_attention(q, cache, valid_len, qpos, *, kv_bits, hd,
 
     def layout(mesh):
         # the cache's kv heads lie on ``shard_axis`` where it divides them
-        # (the serving ShardPlan's rule); queries and outputs follow them
+        # (the serving ShardPlan's rule): axis 2 is the ring's head axis,
+        # the pool's lane axis (whole heads per shard) and the scale
+        # planes' head axis; queries and outputs follow them
         from jax.sharding import PartitionSpec as P
 
         from repro.parallel.sharding import _axis_size, spec_on_mesh

@@ -64,7 +64,7 @@ def init_kv_cache(cfg, batch, max_len, dtype=jnp.bfloat16):
             "v_scale": jnp.zeros((batch, size, kvh), jnp.bfloat16),
         }
     if bits in (4, 2):
-        hd_words = -(-hd // (32 // bits))
+        hd_words = row_width(hd, bits)
         return {
             "k": jnp.zeros((batch, size, kvh, hd_words), jnp.int32),
             "v": jnp.zeros((batch, size, kvh, hd_words), jnp.int32),
@@ -82,16 +82,27 @@ def init_kv_cache(cfg, batch, max_len, dtype=jnp.bfloat16):
 def init_paged_kv_cache(cfg, num_pages, page_size, dtype=jnp.bfloat16):
     """Paged KV pool: ``num_pages`` pages of ``page_size`` token rows.
 
-    Same per-row layouts as :func:`init_kv_cache` with the slot-contiguous
-    ``[B, S, ...]`` leading dims replaced by ``[P, page_size, ...]`` — the
-    kv-head axis stays axis 2, so the serving kv-head shardings
-    (DESIGN.md §15) apply to pools unchanged while the page axis
-    replicates.  One page-id space serves every attention layer: layer
-    ``i``'s pool is indexed by the same block tables (serve/pages.py).
-    Sub-byte layouts additionally require ``page_size`` to be a multiple
-    of the word-packing tail (serve/pages.validate_page_size) so each
-    page holds whole int32 words and dequantizes independently; the
-    per-(pos, kv-head) scale planes page alongside the words.
+    The value leaves are stored lane-flat: ``k``/``v`` are
+    ``[P, page_size, KVH * w]``, one token row holding its KVH heads' rows
+    side by side, where ``w`` is the per-head row width (``hd`` at bf16 and
+    int8, ``ceil(hd / (32 // kv_bits))`` int32 words at 4/2 bits).  The
+    per-(pos, kv-head) scale planes stay ``[P, page_size, KVH]``.  The
+    words and their bit order are those of :func:`init_kv_cache`; only the
+    leaf's shape differs.  Flat, because a minor dimension of 8 int32
+    words makes the TPU's default layout put the page axis minor-most (a
+    minor dim of 8 would pad 16x to 128 lanes), so a 4-D pool was copied
+    into the row scatter's layout, back, and again into the attention
+    kernel's flat view on every step; flat, the scatter and the kernel
+    both read the stored layout.  Readers that need ``[..., KVH, w]``
+    reshape what they gathered, never the pool.
+
+    Under serving TP the lane axis shards over the kv-head axis where it
+    divides KVH (each shard holds whole heads) and the page axis
+    replicates (DESIGN.md §15, §18).  One page-id space serves every
+    attention layer: layer ``i``'s pool is indexed by the same block
+    tables (serve/pages.py).  Sub-byte layouts additionally require
+    ``page_size`` to be a multiple of the word-packing tail
+    (serve/pages.validate_page_size).
 
     Sliding-window archs keep the unpaged ring (ring slot reuse and page
     indirection do not compose; the engine rejects the combination).
@@ -103,27 +114,27 @@ def init_paged_kv_cache(cfg, num_pages, page_size, dtype=jnp.bfloat16):
     hd = cfg.resolved_head_dim
     kvh = cfg.num_kv_heads
     bits = getattr(cfg.quant, "kv_bits", 0)
-    if bits == 8:
-        return {
-            "k": jnp.zeros((num_pages, page_size, kvh, hd), jnp.int8),
-            "v": jnp.zeros((num_pages, page_size, kvh, hd), jnp.int8),
-            "k_scale": jnp.zeros((num_pages, page_size, kvh), jnp.bfloat16),
-            "v_scale": jnp.zeros((num_pages, page_size, kvh), jnp.bfloat16),
-        }
-    if bits in (4, 2):
-        hd_words = -(-hd // (32 // bits))
-        return {
-            "k": jnp.zeros((num_pages, page_size, kvh, hd_words), jnp.int32),
-            "v": jnp.zeros((num_pages, page_size, kvh, hd_words), jnp.int32),
-            "k_scale": jnp.zeros((num_pages, page_size, kvh), jnp.bfloat16),
-            "v_scale": jnp.zeros((num_pages, page_size, kvh), jnp.bfloat16),
-        }
-    if bits not in (0, 16):
+    if bits not in (0, 16, 8, 4, 2):
         raise ValueError(f"unsupported kv_bits {bits}; expected 0/16/8/4/2")
+    lanes = kvh * row_width(hd, bits)
+    if bits in (8, 4, 2):
+        stored = jnp.int8 if bits == 8 else jnp.int32
+        return {
+            "k": jnp.zeros((num_pages, page_size, lanes), stored),
+            "v": jnp.zeros((num_pages, page_size, lanes), stored),
+            "k_scale": jnp.zeros((num_pages, page_size, kvh), jnp.bfloat16),
+            "v_scale": jnp.zeros((num_pages, page_size, kvh), jnp.bfloat16),
+        }
     return {
-        "k": jnp.zeros((num_pages, page_size, kvh, hd), dtype),
-        "v": jnp.zeros((num_pages, page_size, kvh, hd), dtype),
+        "k": jnp.zeros((num_pages, page_size, lanes), dtype),
+        "v": jnp.zeros((num_pages, page_size, lanes), dtype),
     }
+
+
+def row_width(hd, kv_bits):
+    """Stored values (bf16 / int8) or int32 words (4 / 2 bits) per kv head
+    in one cached token row."""
+    return -(-hd // (32 // kv_bits)) if kv_bits in (4, 2) else hd
 
 
 def _kv_quantize(x, bits=8):
@@ -225,30 +236,35 @@ def precompute_cross_kv(p, cfg, enc_out, *, quant_mode="none"):
     return k, v
 
 
-def _constrain_kv_heads(tree, axis):
+def _constrain_kv_heads(tree, axis, kvh=None):
     """Pin cache-layout tensors to the serving kv-head shard axis.
 
     ``axis`` is the mesh axis the serving ShardPlan sharded the kv-head
     dim over (DESIGN.md §15); the constraint keeps the quantize -> pack ->
     scatter write chain head-local so GSPMD neither gathers the incoming
     [B, s, KVH, hd] slice nor reshards the ring between steps.  Applies to
-    K/V (and packed-word) tensors [B, S, KVH, hd|words] and the
-    per-(pos, kv-head) scale planes [B, S, KVH]; no-op when ``axis`` is
-    None or outside a mesh context (sharding.constrain guards)."""
+    K/V (and packed-word) tensors [B, S, KVH, hd|words], the
+    per-(pos, kv-head) scale planes [B, S, KVH], and — given ``kvh`` —
+    the lane-flat pool leaves [P, ps, KVH * w], whose last axis shards
+    only where ``axis`` divides ``kvh`` so that a shard holds whole heads;
+    no-op when ``axis`` is None or outside a mesh context
+    (sharding.constrain guards)."""
     if axis is None:
         return tree
-    from repro.parallel.sharding import constrain
+    from repro.parallel.sharding import constrain, constrain_lanes
 
-    def one(t):
+    def one(name, t):
         if t.ndim == 4:
             return constrain(t, None, None, axis, None)
+        if kvh is not None and name in ("k", "v"):
+            return constrain_lanes(t, axis, kvh)
         if t.ndim == 3:
             return constrain(t, None, None, axis)
         return t
 
     if isinstance(tree, dict):
-        return {k: one(v) for k, v in tree.items()}
-    return one(tree)
+        return {k: one(k, v) for k, v in tree.items()}
+    return one(None, tree)
 
 
 def _fused_decode_epilogue(p, cfg, q, read_cache, valid_len, positions,
@@ -334,11 +350,14 @@ def attention_apply(p, cfg, x, *, positions, quant_mode="none",
       * cross-attention: kv_x (encoder states) given; non-causal, no RoPE
         ring-buffer concerns.
       * paged decode: ``block_tables`` [B, n_pages] maps each row's
-        logical page j to a physical page of a pooled cache
-        ([P, page_size, KVH, ...], init_paged_kv_cache).  Writes scatter
-        through the table; reads gather the row's pages back into a
-        logical [B, n_pages*page_size, ...] view INSIDE the q-chunk body,
-        so fused sub-byte dequant is preserved bit-exactly (positions the
+        logical page j to a physical page of a pooled cache stored
+        lane-flat (``k``/``v`` [P, page_size, KVH * w], scale planes
+        [P, page_size, KVH]; init_paged_kv_cache says why).  Writes
+        scatter each incoming token row into the pool in place through
+        the table; the fused read walks the pool through the table, and
+        the legacy read gathers the row's pages back into a logical
+        [B, n_pages*page_size, KVH, ...] view INSIDE the q-chunk body, so
+        fused sub-byte dequant is preserved bit-exactly (positions the
         mask admits hold values identical to the unpaged ring, and masked
         rows contribute exactly-zero probability).  Vector cache_index
         only; sliding-window archs stay unpaged (DESIGN.md §18).
@@ -413,7 +432,8 @@ def attention_apply(p, cfg, x, *, positions, quant_mode="none",
             # so the unpaged no-window position map applies verbatim
             kv_pos = _ring_positions_batch(idx + vlen - 1, size,
                                            0)                  # [B, size]
-            new_cache = _constrain_kv_heads(new_cache, kv_shard_axis)
+            new_cache = _constrain_kv_heads(new_cache, kv_shard_axis,
+                                            cfg.num_kv_heads)
             if _use_fused_decode(window, kv_x, idx, sq):
                 # zero-copy step: the fused read walks the pool through
                 # the block table, so the [B, size] gather never happens
@@ -602,11 +622,15 @@ def _cache_write_paged(cache, k, v, pages, rows, valid, kv_bits=0):
     redirected past the pool (scatter ``mode='drop'``), exactly like the
     ragged ring write.  Quantization/word-packing happen per incoming
     token row, so the stored words and scale planes are value-identical
-    to the unpaged layout at the same absolute positions."""
+    to the unpaged layout at the same absolute positions.  The small
+    [B, s, KVH, w] update is flattened to the pool's lane-flat rows
+    [B, s, KVH * w]; the pool itself is never reshaped, so the scatter
+    writes the stored layout in place (init_paged_kv_cache)."""
     num_pages = cache["k"].shape[0]
     tgt = jnp.where(valid, pages, num_pages)
 
     def put(buf, val):
+        val = val.reshape(*rows.shape, buf.shape[-1])
         return buf.at[tgt, rows].set(val.astype(buf.dtype), mode="drop")
 
     if "k_scale" in cache:
@@ -622,17 +646,26 @@ def _paged_cache_read(cache, block_tables, dtype, kv_bits=0, hd=None):
     """Gather each row's pages into the logical [B, n_pages*ps, KVH, ...]
     view and dequantize.  Called inside the q-chunk body (kv_fn), so the
     gather + fused unpack/dequant stay per chunk — the full-precision
-    cache never exists whole, same as the unpaged read path."""
+    cache never exists whole, same as the unpaged read path.  The
+    lane-flat value rows are split into heads after the gather."""
+    from repro.kernels.ulppack_attention import pool_kv_heads
+
+    kvh = pool_kv_heads(cache, hd)
+
     def gather(buf):
-        g = buf[block_tables]                # [B, n_pages, ps, KVH, ...]
-        return g.reshape(g.shape[0], -1, *g.shape[3:])
+        g = buf[block_tables]            # [B, n_pages, ps, lanes]
+        return g.reshape(g.shape[0], -1, g.shape[-1])
+
+    def heads(buf):                      # [B, n_pages * ps, KVH, w]
+        g = gather(buf)
+        return g.reshape(*g.shape[:2], kvh, -1)
 
     if "k_scale" in cache:
-        return (_kv_dequantize(gather(cache["k"]), gather(cache["k_scale"]),
+        return (_kv_dequantize(heads(cache["k"]), gather(cache["k_scale"]),
                                dtype, kv_bits, hd),
-                _kv_dequantize(gather(cache["v"]), gather(cache["v_scale"]),
+                _kv_dequantize(heads(cache["v"]), gather(cache["v_scale"]),
                                dtype, kv_bits, hd))
-    return gather(cache["k"]), gather(cache["v"])
+    return heads(cache["k"]), heads(cache["v"])
 
 
 def _ring_positions_batch(last, size, window):

@@ -281,7 +281,8 @@ def init_caches(cfg, batch_size, max_len, dtype=jnp.bfloat16, *,
     """Per-layer decode caches sized for max_len (ring-bounded for SWA).
 
     With ``page_size``/``num_pages`` the attention caches are paged pools
-    ([num_pages, page_size, KVH, ...], one shared page-id space across
+    (values lane-flat [num_pages, page_size, KVH * w], scale planes
+    [num_pages, page_size, KVH], one shared page-id space across
     layers, DESIGN.md §18) instead of slot-contiguous rings; recurrent
     sub-caches (mamba/xLSTM) keep their ``batch_size`` slot rows either
     way — only attention KV pages."""

@@ -110,6 +110,19 @@ def constrain(x, *axes):
         x, NamedSharding(mesh, spec_on_mesh(mesh, x.shape, *axes)))
 
 
+def constrain_lanes(x, axis, groups):
+    """Constrain the last axis of ``x``, ``groups`` equal blocks side by
+    side (a lane-flat page-pool leaf holds one block per kv head), to
+    ``axis`` where the axis divides ``groups``, so every shard holds whole
+    blocks; else that axis replicates.  No-op without an active mesh."""
+    mesh = _ACTIVE_MESH[-1]
+    if mesh is None:
+        return x
+    lane = spec_on_mesh(mesh, (groups,), axis)[0]
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(mesh, P(*([None] * (x.ndim - 1)), lane)))
+
+
 def constrain_like_params(tree, cfg):
     """Constrain a param-shaped pytree (grads, accumulators) to the param
     sharding rules — keeps scan-carried gradient accumulators sharded instead
@@ -286,11 +299,15 @@ def cache_shardings(caches, cfg, mesh: Mesh, global_batch: int,
     size.
 
     ``paged=True`` (with ``kv_head_shard``) is the same layout over a page
-    pool (DESIGN.md §18): attention leaves are ``[P, page_size, KVH, ...]``
-    — the kv-head axis is still axis 2, so the 'model' shard rule carries
-    over unchanged, but the leading *page* axis replicates rather than
-    taking the batch axis: pages are a shared physical resource every
-    slot's block table may reference, not per-sequence rows."""
+    pool (DESIGN.md §18): the scale planes are ``[P, page_size, KVH]`` and
+    the value leaves lane-flat ``[P, page_size, KVH * w]``
+    (attention.init_paged_kv_cache), so the 'model' shard of the kv heads
+    becomes a shard of the lane axis, taken only where 'model' divides
+    KVH (each shard then holds whole heads; otherwise the leaf
+    replicates, as a head axis that does not divide does).  The leading
+    *page* axis replicates rather than taking the batch axis: pages are a
+    shared physical resource every slot's block table may reference, not
+    per-sequence rows."""
     bp = batch_pspec(cfg, mesh, global_batch)
     bp0 = bp[0] if len(bp) else None
     if paged:
@@ -311,6 +328,9 @@ def cache_shardings(caches, cfg, mesh: Mesh, global_batch: int,
             seq_ax = "model" if seq_shard else None
             return NamedSharding(mesh, _guard(mesh, shape,
                                               P(bp0, seq_ax, None)))
+        if kv_head_shard and paged and re.search(r"attn/(k|v)$", ps):
+            heads = _guard(mesh, (cfg.num_kv_heads,), P("model"))[0]
+            return NamedSharding(mesh, P(None, None, heads))
         if kv_head_shard and re.search(r"attn/(k|v)$", ps):
             return NamedSharding(mesh, _guard(
                 mesh, shape, P(bp0, None, "model", None)))

@@ -31,7 +31,9 @@ Layout scheme — chosen so sub-byte packing stays exact under sharding:
   and of the [B, S, KVH] scale planes) over 'model' — quantization,
   word-packing, ring writes and fused-dequant reads are all per-(pos,
   kv-head) local, so a head shard never touches another shard's words
-  (parallel/sharding.cache_shardings(kv_head_shard=True)).  Recurrent
+  (parallel/sharding.cache_shardings(kv_head_shard=True)).  A page
+  pool's lane-flat value leaves [P, ps, KVH * w] shard their last axis
+  instead, in whole heads where 'model' divides KVH.  Recurrent
   states (mamba conv/ssm, xLSTM C/n/m) shard their channel dims via the
   same rules.
 
@@ -122,10 +124,13 @@ class ShardPlan:
 
     def cache_shardings(self, caches, cfg, batch: int, *,
                         paged: bool = False):
-        """``paged=True``: the attention leaves are page pools
-        [P, page_size, KVH, ...] — kv-head rule unchanged (KVH is still
-        axis 2), but the page axis replicates: pages are shared physical
-        capacity any slot's block table may point into (DESIGN.md §18)."""
+        """``paged=True``: the attention leaves are page pools, values
+        lane-flat [P, page_size, KVH * w] and scale planes
+        [P, page_size, KVH] — the kv-head shard becomes a shard of the
+        lane axis, head-local where the axis divides KVH (else the leaf
+        replicates), and the page axis replicates: pages are shared
+        physical capacity any slot's block table may point into
+        (DESIGN.md §18)."""
         return sharding_lib.cache_shardings(
             caches, cfg, self.mesh, batch, kv_head_shard=True, paged=paged)
 

@@ -50,6 +50,12 @@ def _make_cache(rng, b, s, kvh, hd, kv_bits):
     return {"k": k, "v": v}
 
 
+def _lane_flat(pool):
+    """[P, ps, KVH, w] value leaves -> the stored pool [P, ps, KVH * w]
+    (attention.init_paged_kv_cache); scale planes unchanged."""
+    return {kk: vv.reshape(*vv.shape[:2], -1) for kk, vv in pool.items()}
+
+
 def _dense_reference(q, cache, valid_len, qpos, kv_bits, hd):
     """Materialize the whole dequantized view; masked softmax; rows with
     nothing visible return exact zero (matching the fused l == 0 guard)."""
@@ -88,7 +94,7 @@ def test_fused_matches_dense_reference(kv_bits, paged, backend):
         bt = jnp.arange(b * n_pages, dtype=jnp.int32).reshape(b, n_pages)
         logical = {kk: vv.reshape(b, ps * n_pages, *vv.shape[2:])
                    for kk, vv in pool.items()}
-        cache, s = pool, ps * n_pages
+        cache, s = _lane_flat(pool), ps * n_pages
     else:
         s = 32
         cache = _make_cache(rng, b, s, kvh, hd, kv_bits)
@@ -140,8 +146,8 @@ def test_fused_paged_scrambled_block_table():
     want = _dense_reference(q, logical, valid_len, qpos, 4, hd)
     for backend in ("xla", "pallas"):
         got = ulppack_attention.fused_decode_attention(
-            q, pool, valid_len, qpos, kv_bits=4, hd=hd, block_tables=bt,
-            backend=backend)
+            q, _lane_flat(pool), valid_len, qpos, kv_bits=4, hd=hd,
+            block_tables=bt, backend=backend)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-4, atol=1e-5)
 

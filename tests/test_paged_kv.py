@@ -413,3 +413,53 @@ def test_paged_drain_restore_keeps_warm_prefix(tiny, tmp_path):
     # prompts[0] resubmitted verbatim: its prefix must hit the restored
     # index without recomputation beyond the final row
     assert eng.capacity_report()["prefix_hit_tokens"] > 0
+
+
+# ---------------------------------------------------------------------------
+# The lane-flat pool: same stored words as the ring, same tokens
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("kv_bits", [0, 8, 4, 2])
+def test_lane_flat_pool_stores_the_ring_words(kv_bits):
+    """The pool's value leaves are [P, page_size, KVH * w]: at every
+    written (page, row, head) they hold the words (and the scale planes
+    the scales) that the unpaged ring holds at the same absolute
+    position, and paged greedy decode emits the ring's tokens."""
+    from repro.models import attention
+
+    cfg = kv_cfg(kv_bits)
+    params = lm.init_params(jax.random.PRNGKey(2), cfg)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (21, 9)]
+    engines = {}
+    for paged in (False, True):
+        eng = ServingEngine(cfg, params, config=EngineConfig(
+            max_batch=2, max_len=48, packed=False, prefill_chunk=8,
+            paged=paged, page_size=16, prefix_sharing=False))
+        for i, p in enumerate(prompts):
+            assert eng.submit(Request(uid=i, prompt=p, max_new_tokens=6))
+        for _ in range(5):              # prefill chunks, then decodes
+            eng.step()
+        engines[paged] = eng
+    ring, pool = engines[False], engines[True]
+    np.testing.assert_array_equal(ring.slot_pos, pool.slot_pos)
+    assert all(pool.slot_req) and pool.slot_pos.min() > 9
+
+    ps, kvh = pool.page_size, cfg.num_kv_heads
+    w = attention.row_width(cfg.resolved_head_dim, kv_bits)
+    for ring_c, pool_c in zip(ring.caches, pool.caches):
+        r, p = ring_c["attn"], pool_c["attn"]
+        assert set(p) == set(r)
+        assert p["k"].shape == (pool.num_pages, ps, kvh * w)
+        for s, n in enumerate(pool.slot_pos):
+            pos = np.arange(n)
+            where = (pool.block_tables[s, pos // ps], pos % ps)
+            for name, leaf in p.items():
+                want = np.asarray(r[name])[s, pos]
+                got = np.asarray(leaf)[where].reshape(want.shape)
+                np.testing.assert_array_equal(got, want, err_msg=name)
+
+    tokens = [{r.uid: tuple(r.output) for r in eng.run_to_completion()}
+              for eng in (ring, pool)]
+    assert tokens[0] == tokens[1] and len(tokens[0]) == 2

@@ -335,10 +335,10 @@ TP_SCRIPT = textwrap.dedent("""
 
     b, h, hd, ps, n_pages = 2, 8, 16, 16, 3
     pool = b * n_pages
-    cache = {
-        "k": jnp.asarray(rng.integers(-2**31, 2**31, (pool, ps, h, 2)),
+    cache = {        # the lane-flat pool: h kv heads of 2 words a row
+        "k": jnp.asarray(rng.integers(-2**31, 2**31, (pool, ps, h * 2)),
                          jnp.int32),
-        "v": jnp.asarray(rng.integers(-2**31, 2**31, (pool, ps, h, 2)),
+        "v": jnp.asarray(rng.integers(-2**31, 2**31, (pool, ps, h * 2)),
                          jnp.int32),
         "k_scale": jnp.asarray(rng.uniform(.02, .2, (pool, ps, h)),
                                jnp.bfloat16),

@@ -13,7 +13,9 @@ worker imports this module.  Keep these tests in this one file.
 """
 
 import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +27,7 @@ from repro.core.packing import PackSpec
 from repro.kernels import ops  # noqa: F401 (registers the backends)
 from repro.kernels import plan as plan_lib
 from repro.kernels import ulppack_attention
+from repro.models import attention
 
 CFG = configs.get_config("stablelm-1.6b")
 SPEC = PackSpec.from_config(CFG.quant)
@@ -97,8 +100,8 @@ def test_paged_attention_decode_compiles(one_chip, kv_bits):
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
-    cache = {"k": sds((pool, page, KVH, words), jnp.int32),
-             "v": sds((pool, page, KVH, words), jnp.int32),
+    cache = {"k": sds((pool, page, KVH * words), jnp.int32),
+             "v": sds((pool, page, KVH * words), jnp.int32),
              "k_scale": sds((pool, page, KVH), jnp.bfloat16),
              "v_scale": sds((pool, page, KVH), jnp.bfloat16)}
     plan = chip(plan_lib.plan_attention_decode(
@@ -114,3 +117,61 @@ def test_paged_attention_decode_compiles(one_chip, kv_bits):
                         sds((b,), jnp.int32), sds((b, 1), jnp.int32),
                         sds((b, n_pages), jnp.int32))
     assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("kv_bits", [4, 2])
+def test_paged_decode_writes_the_pool_in_place(one_chip, kv_bits):
+    """One layer of the decode step at the chat cell's pool (1536 pages
+    of 16 rows, 16 rows in the batch, 64 table entries), caches donated:
+    the paged write then the attention kernel.  The word leaves enter in
+    the row-major layout that the scatter and the kernel both read, no
+    synchronous copy of a whole word leaf is left (a 4-D
+    [P, ps, KVH, words] pool took three per leaf: into the scatter's
+    layout, back, and into the kernel's flat view), and every pool leaf
+    is aliased to its output."""
+    b, page, n_pages, pool = 16, 16, 64, 1536
+    cfg = CFG.replace(quant=dataclasses.replace(CFG.quant, kv_bits=kv_bits))
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    cache = jax.tree.map(lambda x: sds(x.shape, x.dtype), jax.eval_shape(
+        lambda: attention.init_paged_kv_cache(cfg, pool, page)))
+    leaf = cache["k"].shape
+    plan = chip(plan_lib.plan_attention_decode(
+        b, n_pages * page, H, KVH, HD, kv_bits, page_size=page,
+        backend="pallas"))
+
+    def step(q, k, v, cache, index, bt):
+        wpos = index[:, None]
+        phys = jnp.take_along_axis(bt, wpos // page, axis=1)
+        cache = attention._cache_write_paged(
+            cache, k, v, phys, wpos % page, jnp.ones((b, 1), bool),
+            kv_bits)
+        out = ulppack_attention.fused_decode_attention(
+            q, cache, index + 1, wpos, kv_bits=kv_bits, hd=HD, plan=plan,
+            block_tables=bt)
+        return out, cache
+
+    rows = sds((b, 1, KVH, HD), jnp.bfloat16)
+    txt = jax.jit(step, donate_argnums=(3,)).lower(
+        sds((b, 1, H, HD), jnp.bfloat16), rows, rows, cache,
+        sds((b,), jnp.int32), sds((b, n_pages), jnp.int32)
+    ).compile().as_text()
+    head = txt.splitlines()[0]
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", head)[1]
+    word = re.compile(r"s32\[(\d+(?:,\d+)*)\](\{[^}]*\})")
+    layouts = [lay for dims, lay in word.findall(entry)
+               if math.prod(map(int, dims.split(","))) == math.prod(leaf)]
+    assert len(layouts) == 2, entry
+    assert all(lay.startswith("{2,1,0") for lay in layouts), layouts
+    copies = [ln for ln in txt.splitlines()
+              if re.search(r"= s32\[[\d,]+\]\{[^}]*\} copy\(", ln)
+              and math.prod(map(int, word.search(ln)[1].split(",")))
+              == math.prod(leaf)]
+    assert not copies, copies[:3]
+    # flattened arguments: q 0, k 1, v 2, the pool's leaves 3-6 (sorted
+    # keys k, k_scale, v, v_scale), index 7, table 8
+    aliased = {int(p) for p in re.findall(
+        r"\{\d*\}: \((\d+), \{\}", head)}
+    assert {3, 4, 5, 6} <= aliased, head[:400]
