@@ -29,10 +29,11 @@ Two registered backends for the ``attention_decode`` op:
              more than one query row, and GSPMD partitions it under a mesh.
   'pallas' — the real kernel: grid (batch, kv-split), online-softmax carry
              in VMEM scratch, shift-mask word unpack in-kernel, and — paged
-             — a scalar-prefetched block table whose entries ARE the
-             kv-split block indices (``PrefetchScalarGridSpec``), i.e. the
-             block-table walk happens in the kernel's index_map.  Runs
-             interpreted off-TPU (plan.default_interpret()).
+             — a scalar-prefetched block table that the kernel walks
+             itself, ``plan.chunks`` table entries per grid step, copying
+             only the entries that hold live tokens from the pool in HBM
+             into a double-buffered VMEM scratch.  Runs interpreted
+             off-TPU (plan.default_interpret()).
 
 ``fused_decode_attention`` is the models/attention.py entry point; the
 ``REPRO_FUSED_DECODE=0`` environment kill-switch (read at trace time;
@@ -247,14 +248,14 @@ def _attention_decode_xla(plan, q, cache, valid_len, qpos,
 # contractions or the in-register word reshapes of the 'xla' path, so the
 # kernel works on a lane-flat cache row: one token row is its KVH kv-head
 # words side by side, [KVH * hdw] lanes.  The page pool is stored that way
-# (attention.init_paged_kv_cache), so the paged kernel reads it as stored;
-# on a TPU a reshape of a 4-D [N, rows, KVH, hdw] cache into this view is
-# a relayout copy of the whole cache, which the contiguous ring still
-# pays.  Field j of every word is one shift-mask plane u_j [span, KVH*hdw]
-# holding dims w*per + j of each head.  The query side is laid out to match
-# in the wrapper: per field j, a block-diagonal [H, KVH*hdw] matrix whose
-# row (kvh, g) holds that query head's dims w*per + j in kv head kvh's
-# lane block and zeros elsewhere.  Then
+# (attention.init_paged_kv_cache), so the paged kernel copies its pages as
+# stored; on a TPU a reshape of a 4-D [N, rows, KVH, hdw] cache into this
+# view is a relayout copy of the whole cache, which the contiguous ring
+# still pays.  Field j of every word is one shift-mask plane
+# u_j [span, KVH*hdw] holding dims w*per + j of each head.  The query side
+# is laid out to match in the wrapper: per field j, a block-diagonal
+# [H, KVH*hdw] matrix whose row (kvh, g) holds that query head's dims
+# w*per + j in kv head kvh's lane block and zeros elsewhere.  Then
 #
 #     scores [H, span] = sum_j  qbd_j @ u_j^T
 #     acc_j  [H, KVH*hdw] += p @ v_j
@@ -268,80 +269,207 @@ def _attention_decode_xla(plan, q, cache, valid_len, qpos,
 _HI = jax.lax.Precision.HIGHEST
 
 
-def _decode_kernel(*refs, kv_bits, per, zp, span, paged, quantized):
-    """Grid (B, n_groups): one batch row x one KV group per program; the
-    online-softmax carry lives in VMEM scratch across the group sweep.
-    Group j covers logical token rows j*span .. j*span+span (for the paged
-    variant the index_map already picked the group's pool page)."""
-    if paged:
-        _, vl_ref, qp_ref, *refs = refs
-    else:
-        vl_ref, qp_ref, *refs = refs
-    if quantized:
-        (qbd_ref, qs_ref, e_ref, k_ref, v_ref, sk_ref, sv_ref, o_ref,
-         m_ref, l_ref, acc_ref) = refs
-    else:
-        (qbd_ref, qs_ref, e_ref, k_ref, v_ref, o_ref,
-         m_ref, l_ref, acc_ref) = refs
-    i, j = pl.program_id(0), pl.program_id(1)
-    valid_len, qpos = vl_ref[i], qp_ref[i]
+def _group_update(qbd_ref, qs_ref, e_ref, m_ref, l_ref, acc_ref, kw, vw,
+                  sk, sv, t0, valid_len, qpos, *, kv_bits, per, zp):
+    """Fold one group of cache rows into the online-softmax carry: the
+    per-group math both Pallas variants share.  ``kw``/``vw`` [span,
+    KVH*hdw] are the group's stored rows, logical positions t0 ..
+    t0+span, ``sk``/``sv`` [span, KVH] their scale planes (None for float
+    caches).  A row at ``pos >= valid_len`` or ``pos > qpos`` may hold
+    anything, NaN scales and stale VMEM included: its probabilities are
+    zeroed before and after the value-scale multiply, and float value
+    rows are zeroed, so no unmasked product touches it."""
+    span = kw.shape[0]
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    def planes(ref):
-        x = ref[0]
+    def planes(x):
         if per == 1:
             return [x.astype(jnp.float32)]
         mask = (1 << kv_bits) - 1
         return [((x >> (kv_bits * f)) & mask).astype(jnp.float32)
                 for f in range(per)]
 
-    def expand(scale_ref):
+    def expand(scale):
         # [span, KVH] scale plane -> [H, span] rows via the one-hot E
         return jax.lax.dot_general(
-            e_ref[...], scale_ref[0].astype(jnp.float32),
+            e_ref[...], scale.astype(jnp.float32),
             (((1,), (1,)), ((), ())), precision=_HI,
             preferred_element_type=jnp.float32)
 
-    @pl.when(j * span < valid_len)          # groups past every live row skip
+    s = None
+    for f, u in enumerate(planes(kw)):
+        t = jax.lax.dot_general(qbd_ref[0, f], u,
+                                (((1,), (1,)), ((), ())), precision=_HI,
+                                preferred_element_type=jnp.float32)
+        s = t if s is None else s + t                       # [H, span]
+    if sk is not None:
+        s = expand(sk) * (s - zp * qs_ref[0] if zp else s)
+    pos = t0 + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+    ok = (pos < valid_len) & (pos <= qpos)
+    s = jnp.where(ok, s, NEG_INF)
+    m = m_ref[...]
+    mn = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    corr = jnp.exp(m - mn)
+    p = jnp.where(ok, jnp.exp(s - mn), 0.0)
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+    m_ref[...] = mn
+    u_v = planes(vw)
+    if sv is not None:
+        p = jnp.where(ok, p * expand(sv), 0.0)
+    else:                   # float rows past the live prefix may be NaN
+        rows = t0 + jax.lax.broadcasted_iota(jnp.int32, (span, 1), 0)
+        u_v = [jnp.where(rows < valid_len, u, 0.0) for u in u_v]
+    psum = jnp.sum(p, axis=1, keepdims=True)
+    for f, u in enumerate(u_v):
+        av = jax.lax.dot_general(p, u, (((1,), (0,)), ((), ())),
+                                 precision=_HI,
+                                 preferred_element_type=jnp.float32)
+        if zp:
+            av = av - zp * psum
+        acc_ref[f] = acc_ref[f] * corr + av
+
+
+def _carry_init(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _carry_finish(o_ref, l_ref, acc_ref):
+    ll = l_ref[...]
+    o_ref[0] = acc_ref[...] / jnp.where(ll == 0, 1.0, ll)[None]
+
+
+def _decode_kernel(vl_ref, qp_ref, qbd_ref, qs_ref, e_ref, *refs, kv_bits,
+                   per, zp, span, quantized):
+    """Contiguous ring, grid (B, n_groups): one batch row x one KV group
+    per program, the group's rows delivered by token-sliced BlockSpecs;
+    the online-softmax carry lives in VMEM scratch across the sweep."""
+    if quantized:
+        k_ref, v_ref, sk_ref, sv_ref, o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
+        sk_ref = sv_ref = None
+    i, j = pl.program_id(0), pl.program_id(1)
+    valid_len = vl_ref[i]
+
+    @pl.when(j == 0)
+    def _init():
+        _carry_init(m_ref, l_ref, acc_ref)
+
+    @pl.when(j * span < valid_len)          # groups past the live rows skip
     def _group():
-        u_k = planes(k_ref)
-        s = None
-        for f in range(per):
-            t = jax.lax.dot_general(qbd_ref[0, f], u_k[f],
-                                    (((1,), (1,)), ((), ())), precision=_HI,
-                                    preferred_element_type=jnp.float32)
-            s = t if s is None else s + t                   # [H, span]
-        if quantized:
-            s = expand(sk_ref) * (s - zp * qs_ref[0] if zp else s)
-        pos = j * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
-        ok = (pos < valid_len) & (pos <= qpos)
-        s = jnp.where(ok, s, NEG_INF)
-        m = m_ref[...]
-        mn = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m - mn)
-        p = jnp.where(ok, jnp.exp(s - mn), 0.0)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        m_ref[...] = mn
-        if quantized:
-            p = p * expand(sv_ref)
-        psum = jnp.sum(p, axis=1, keepdims=True)
-        for f, u in enumerate(planes(v_ref)):
-            av = jax.lax.dot_general(p, u, (((1,), (0,)), ((), ())),
-                                     precision=_HI,
-                                     preferred_element_type=jnp.float32)
-            if zp:
-                av = av - zp * psum
-            acc_ref[f] = acc_ref[f] * corr + av
+        _group_update(qbd_ref, qs_ref, e_ref, m_ref, l_ref, acc_ref,
+                      k_ref[0], v_ref[0],
+                      None if sk_ref is None else sk_ref[0],
+                      None if sv_ref is None else sv_ref[0],
+                      j * span, valid_len, qp_ref[i], kv_bits=kv_bits,
+                      per=per, zp=zp)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _done():
-        ll = l_ref[...]
-        o_ref[0] = acc_ref[...] / jnp.where(ll == 0, 1.0, ll)[None]
+        _carry_finish(o_ref, l_ref, acc_ref)
+
+
+def _page_lanes(n, interpret):
+    """Lanes one page's copy moves.  A TPU stores a pool leaf's minor axis
+    in whole 128-lane tiles, and Mosaic slices an HBM ref only in whole
+    tiles; so a narrower leaf (the [P, ps, KVH] scale planes, a head
+    shard's words) is copied with its tile's padding lanes, which the
+    kernel slices off.  Interpreted, the leaf is copied as it is."""
+    return n if interpret else -(-n // 128) * 128
+
+
+def _paged_decode_kernel(bt_ref, vl_ref, qp_ref, qbd_ref, qs_ref, e_ref,
+                         *refs, kv_bits, per, zp, page_size, chunks,
+                         quantized):
+    """Paged pool, grid (B, ceil(n_pages / chunks)): program (i, j) is row
+    i's table entries j*chunks .. j*chunks+chunks.  The pool stays in HBM;
+    the kernel walks the scalar-prefetched block table itself and DMAs
+    only the entries that hold live tokens (whole lane tiles,
+    :func:`_page_lanes`) into one half of a double buffer, so a dead step
+    issues no copy and does no compute.  Each live step first starts the
+    next live step's copies into the other half (the row's next step,
+    else the next row's first), then waits for its own.  Copies and waits
+    run in a ``fori_loop`` over the step's live pages, so the kernel body
+    is the same size for any ``chunks``.  Grid order is sequential: the
+    half in use is carried across programs in SMEM."""
+    if quantized:
+        (k_hbm, v_hbm, sk_hbm, sv_hbm, o_ref, m_ref, l_ref, acc_ref,
+         kbuf, vbuf, skbuf, svbuf, sems, slot_ref) = refs
+        pairs = ((k_hbm, kbuf), (v_hbm, vbuf), (sk_hbm, skbuf),
+                 (sv_hbm, svbuf))
+    else:
+        (k_hbm, v_hbm, o_ref, m_ref, l_ref, acc_ref, kbuf, vbuf, sems,
+         slot_ref) = refs
+        pairs = ((k_hbm, kbuf), (v_hbm, vbuf))
+    b, n_steps = pl.num_programs(0), pl.num_programs(1)
+    i, j = pl.program_id(0), pl.program_id(1)
+    span = chunks * page_size
+    n_pages = bt_ref.shape[1]
+    valid_len = vl_ref[i]
+
+    def fetch(row, step, slot, start):
+        """Start (or wait for) the copies of ``row``'s step ``step``: its
+        table entries up to the row's last live page."""
+        live = jnp.minimum((vl_ref[row] + page_size - 1) // page_size,
+                           n_pages)
+        count = jnp.clip(live - step * chunks, 0, chunks)
+
+        def one_page(t, carry):
+            page = bt_ref[row, step * chunks + t]
+            rows = pl.ds(pl.multiple_of(t * page_size, page_size),
+                         page_size)
+            for src, dst in pairs:
+                lanes = pl.ds(0, dst.shape[-1])
+                cp = pltpu.make_async_copy(src.at[page, :, lanes],
+                                           dst.at[slot, rows], sems.at[slot])
+                if start:
+                    cp.start()
+                else:
+                    cp.wait()
+            return carry
+
+        jax.lax.fori_loop(0, count, one_page, 0)
+
+    @pl.when((i == 0) & (j == 0))
+    def _first():
+        slot_ref[0] = 0
+
+    @pl.when(j == 0)
+    def _init():
+        _carry_init(m_ref, l_ref, acc_ref)
+
+    @pl.when(j * span < valid_len)
+    def _step():
+        slot = slot_ref[0]
+        prev = jnp.maximum(i - 1, 0)
+        nxt = jnp.minimum(i + 1, b - 1)
+
+        # a row's first step was started by the previous row's last one
+        @pl.when((j == 0) & ((i == 0) | (vl_ref[prev] == 0)))
+        def _own():
+            fetch(i, j, slot, True)
+
+        # the next live step: this row's next, else the next row's first
+        more = (j + 1 < n_steps) & ((j + 1) * span < valid_len)
+
+        @pl.when(more | ((i + 1 < b) & (vl_ref[nxt] > 0)))
+        def _next():
+            fetch(jnp.where(more, i, nxt), jnp.where(more, j + 1, 0),
+                  1 - slot, True)
+
+        fetch(i, j, slot, False)
+        slot_ref[0] = 1 - slot
+        kw, vw, *scales = [buf[slot][:, :src.shape[-1]]
+                           for src, buf in pairs]
+        sk, sv = scales if quantized else (None, None)
+        _group_update(qbd_ref, qs_ref, e_ref, m_ref, l_ref, acc_ref, kw, vw,
+                      sk, sv, j * span, valid_len, qp_ref[i],
+                      kv_bits=kv_bits, per=per, zp=zp)
+
+    @pl.when(j == n_steps - 1)
+    def _done():
+        _carry_finish(o_ref, l_ref, acc_ref)
 
 
 def _pad_tokens(x, multiple):
@@ -369,17 +497,24 @@ def _block_diag_query(q, kvh, per, hdw):
             qsum[:, 0].reshape(b, h, 1))
 
 
+# jitted so that a serving step, which calls it once per layer with the
+# same plan and shapes, traces and lowers the kernel once and not once per
+# layer: that work is paid by every serving process at set-up, warm compile
+# cache or not; the nested jit is inlined, so the step's program is as it was
 @plan_lib.register_backend("attention_decode", "pallas")
+@functools.partial(jax.jit, static_argnums=(0,),
+                   static_argnames=("kv_bits", "hd"))
 def _attention_decode_pallas(plan, q, cache, valid_len, qpos,
                              block_tables=None, *, kv_bits, hd):
     """Pallas flash-decoding kernel; sq == 1 decode only (the dispatcher
     routes wider windows to the 'xla' backend).
 
     Contiguous: grid (B, ceil(Sk / block_k)), token-sliced BlockSpecs.
-    Paged: grid (B, n_pages) — the scalar-prefetched block table IS the
-    pool index_map (``bt[i, j]``), one page per grid step, so the kernel
-    walks each row's page list without materializing the gathered view.
-    ``valid_len``/``qpos`` ride in SMEM as scalar-prefetch operands."""
+    Paged: grid (B, ceil(n_pages / plan.chunks)); the pool stays in HBM
+    and the kernel copies each step's live table entries itself
+    (:func:`_paged_decode_kernel`), so only live pages are read.
+    ``valid_len``/``qpos`` (and the block table) ride in SMEM as
+    scalar-prefetch operands."""
     b, c, h, _ = q.shape
     if c != 1:
         raise ValueError("pallas attention_decode handles sq == 1 only")
@@ -397,61 +532,70 @@ def _attention_decode_pallas(plan, q, cache, valid_len, qpos,
               == jnp.arange(kvh)[None, :]).astype(jnp.float32)
     vl = valid_len.astype(jnp.int32).reshape(b)
     qp = qpos[:, 0].astype(jnp.int32).reshape(b)
-
-    def flat(x):            # [N, rows, KVH, hdw] -> [N, rows, KVH*hdw]
-        return x.reshape(*x.shape[:2], -1)
+    row_map = lambda i, j, *_: (i, 0, 0)
+    row4_map = lambda i, j, *_: (i, 0, 0, 0)
+    const_map = lambda i, j, *_: (0, 0)
+    in_specs = [pl.BlockSpec((1, per, h, width), row4_map),
+                pl.BlockSpec((1, h, 1), row_map),
+                pl.BlockSpec((h, kvh), const_map)]
+    scratch = [pltpu.VMEM((h, 1), jnp.float32),
+               pltpu.VMEM((h, 1), jnp.float32),
+               pltpu.VMEM((per, h, width), jnp.float32)]
+    pool = [cache["k"], cache["v"]]
+    if quantized:
+        pool += [cache["k_scale"], cache["v_scale"]]
+    common = dict(kv_bits=kv_bits, per=per, zp=zp, quantized=quantized)
+    params = None
 
     if paged:
-        span = cache["k"].shape[1]
-        n_groups = block_tables.shape[1]
+        page_size, n_pages = cache["k"].shape[1], block_tables.shape[1]
+        chunks = min(max(1, plan.chunks or 1), n_pages)
+        span = chunks * page_size
+        n_groups = -(-n_pages // chunks)
         bt = jnp.clip(block_tables.astype(jnp.int32), 0,
                       cache["k"].shape[0] - 1)
         prefetch = (bt, vl, qp)
-        kv_map = lambda i, j, bt_, vl_, qp_: (bt_[i, j], 0, 0)
-        row_map = lambda i, j, *_: (i, 0, 0)
-        row4_map = lambda i, j, *_: (i, 0, 0, 0)
-        const_map = lambda i, j, *_: (0, 0)
-        ks, vs = cache["k"], cache["v"]         # stored lane-flat
-        scales = [cache["k_scale"], cache["v_scale"]] if quantized else []
+        # read as stored (lane-flat); pinned to HBM, so that XLA does not
+        # stage a whole leaf in VMEM ahead of the kernel
+        operands = pool if plan.interpret else [
+            pltpu.with_memory_space_constraint(x, pltpu.HBM) for x in pool]
+        in_specs += [pl.BlockSpec(memory_space=pltpu.HBM) for _ in pool]
+        scratch += [pltpu.VMEM((2, span, _page_lanes(x.shape[-1],
+                                                    plan.interpret)),
+                               x.dtype) for x in pool]
+        scratch += [pltpu.SemaphoreType.DMA((2,)),
+                    pltpu.SMEM((1,), jnp.int32)]
+        kern = functools.partial(_paged_decode_kernel, page_size=page_size,
+                                 chunks=chunks, **common)
+        # the double buffer's half in use is carried from row to row
+        params = pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"))
     else:
         skv = cache["k"].shape[1]
         span = min(max(1, plan.block_k or skv), skv)
         if span < skv:
             span = -(-span // 8) * 8
         prefetch = (vl, qp)
-        kv_map = lambda i, j, vl_, qp_: (i, j, 0)
-        row_map = lambda i, j, *_: (i, 0, 0)
-        row4_map = lambda i, j, *_: (i, 0, 0, 0)
-        const_map = lambda i, j, *_: (0, 0)
-        ks = _pad_tokens(flat(cache["k"]), span)
-        vs = _pad_tokens(flat(cache["v"]), span)
-        scales = ([_pad_tokens(cache["k_scale"], span),
-                   _pad_tokens(cache["v_scale"], span)] if quantized
-                  else [])
-        n_groups = ks.shape[1] // span
+        kv_map = lambda i, j, *_: (i, j, 0)
+        # [B, S, KVH, hdw] -> [B, S, KVH*hdw]: a relayout copy on a TPU
+        operands = [_pad_tokens(x.reshape(*x.shape[:2], -1) if x.ndim == 4
+                                else x, span) for x in pool]
+        n_groups = operands[0].shape[1] // span
+        in_specs += [pl.BlockSpec((1, span, x.shape[-1]), kv_map)
+                     for x in operands]
+        kern = functools.partial(_decode_kernel, span=span, **common)
 
-    in_specs = [pl.BlockSpec((1, per, h, width), row4_map),
-                pl.BlockSpec((1, h, 1), row_map),
-                pl.BlockSpec((h, kvh), const_map),
-                pl.BlockSpec((1, span, width), kv_map),
-                pl.BlockSpec((1, span, width), kv_map)]
-    in_specs += [pl.BlockSpec((1, span, kvh), kv_map) for _ in scales]
-    kern = functools.partial(_decode_kernel, kv_bits=kv_bits, per=per,
-                             zp=zp, span=span, paged=paged,
-                             quantized=quantized)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(prefetch),
-        grid=(b, n_groups),
+        num_scalar_prefetch=len(prefetch), grid=(b, n_groups),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, per, h, width), row4_map),
-        scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
-                        pltpu.VMEM((h, 1), jnp.float32),
-                        pltpu.VMEM((per, h, width), jnp.float32)])
+        scratch_shapes=scratch)
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, per, h, width), jnp.float32),
-        interpret=plan.interpret, name="ulppack_attention_decode",
-    )(*prefetch, qbd, qsum, onehot, ks, vs, *scales)
+        compiler_params=params, interpret=plan.interpret,
+        name="ulppack_attention_decode",
+    )(*prefetch, qbd, qsum, onehot, *operands)
     # each head's output is the diagonal (own kv head) block of acc
     out = out.reshape(b, per, kvh, groups, kvh, hdw)
     eye = jnp.eye(kvh, dtype=jnp.float32)[:, None, :, None]

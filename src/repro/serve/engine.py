@@ -115,6 +115,13 @@ class Metrics:
     whether k was too ambitious for the draft's fidelity.  Committed
     tokens still land in ``decode_tokens``, so ``decode_tok_s`` stays
     directly comparable with a non-speculative engine.
+
+    Paged decode reads (DESIGN.md §20): ``decode_pages_live`` sums, over
+    decode launches and their live rows, the table entries that hold live
+    tokens (``ceil(valid_len / page_size)``); ``decode_pages_table`` the
+    rows x table entries of the same launches, every entry a walk of the
+    whole table would read.  ``report()`` derives
+    ``decode_live_page_share`` = live / table.
     """
     prefill_tokens: int = 0
     generated_tokens: int = 0
@@ -132,6 +139,8 @@ class Metrics:
     accepted_tokens: int = 0
     verify_tokens: int = 0
     spec_cycles: int = 0
+    decode_pages_live: int = 0
+    decode_pages_table: int = 0
     ttft_s: list = dataclasses.field(default_factory=list)
     tpot_s: list = dataclasses.field(default_factory=list)
 
@@ -169,6 +178,10 @@ class Metrics:
             "spec_cycles": self.spec_cycles,
             "acceptance_rate": round(div(self.accepted_tokens,
                                          self.drafted_tokens), 3),
+            "decode_pages_live": self.decode_pages_live,
+            "decode_pages_table": self.decode_pages_table,
+            "decode_live_page_share": round(div(self.decode_pages_live,
+                                                self.decode_pages_table), 4),
             "ttft_s": self._dist(self.ttft_s),
             "tpot_s": self._dist(self.tpot_s),
         }
@@ -727,6 +740,7 @@ class ServingEngine:
                         self._ensure_writable(s, int(index[s]),
                                               int(index[s]) + 1)
                     step_args = (jnp.asarray(self.block_tables),)
+                    self._count_decode_pages(span, index, valid)
                 dev_index, dev_valid = jnp.asarray(index), jnp.asarray(valid)
             with Span("engine.launch.decode"), self._mesh_ctx():
                 logits, self.caches = self._decode(
@@ -738,6 +752,18 @@ class ServingEngine:
                 for s in live:
                     self.slot_pos[s] += 1
                     self._emit_token(s, logits[s], decode_pass=True)
+
+    def _count_decode_pages(self, span, index, valid):
+        """Count a decode launch's live table entries against the whole
+        table (Metrics.decode_pages_live / decode_pages_table); the pass's
+        span carries both while a trace records."""
+        rows = valid > 0
+        live = int((-(-(index[rows] + valid[rows]) // self.page_size)).sum())
+        table = self.block_tables.size
+        self.metrics.decode_pages_live += live
+        self.metrics.decode_pages_table += table
+        if Span.is_enabled():
+            span.set_metadata(pages_live=live, pages_table=table)
 
     def _speculative_pass(self, live):
         """One speculative cycle (DESIGN.md §19): draft up to ``k``

@@ -153,6 +153,117 @@ def test_fused_paged_scrambled_block_table():
 
 
 # ---------------------------------------------------------------------------
+# The paged Pallas walk: several table entries per grid step, live pages
+# copied from the pool, nothing else read
+# ---------------------------------------------------------------------------
+
+PS, KVH, HD, H = 4, 2, 16, 4
+
+
+def _walk_case(case, kv_bits=4, seed=0):
+    """(pool [P, PS, KVH, w], table, valid_len, chunks) of one walk case;
+    the logical view of row r is pool[table[r]]."""
+    rng = np.random.default_rng(seed)
+    n_pages, chunks = 6, 2
+    valid = {"partly_live": [7, 11],        # the last live step half full
+             "dead_steps": [5, 2],          # steps 1-2 / 1-2 dead
+             "empty_row": [13, 0, 9],       # no prefetch across row 1
+             "ragged_table": [27, 9],       # 7 entries, steps of 2
+             "scrambled": [24, 10]}[case]
+    if case == "ragged_table":
+        n_pages = 7
+    b = len(valid)
+    pool = _make_cache(rng, b * n_pages + 3, PS, KVH, HD, kv_bits)
+    order = (rng.permutation(b * n_pages + 3)[:b * n_pages]
+             if case == "scrambled" else np.arange(b * n_pages))
+    bt = jnp.asarray(order.reshape(b, n_pages), jnp.int32)
+    return pool, bt, jnp.asarray(valid, jnp.int32), chunks
+
+
+def _walk_plan(chunks, interpret=True):
+    return plan_lib.KernelPlan(
+        op="attention_decode", backend="pallas", interpret=interpret,
+        block_k=chunks * PS, chunks=chunks)
+
+
+def _paged_and_contiguous(pool, bt, valid_len, chunks, kv_bits, seed=1):
+    b = bt.shape[0]
+    q = jnp.asarray(np.random.default_rng(seed).standard_normal(
+        (b, 1, H, HD)), jnp.float32)
+    qpos = valid_len[:, None] - 1
+    plan = _walk_plan(chunks)
+    logical = {kk: vv[bt].reshape(b, -1, *vv.shape[2:])
+               for kk, vv in pool.items()}
+    paged = ulppack_attention.fused_decode_attention(
+        q, _lane_flat(pool), valid_len, qpos, kv_bits=kv_bits, hd=HD,
+        plan=plan, block_tables=bt)
+    ring = ulppack_attention.fused_decode_attention(
+        q, logical, valid_len, qpos, kv_bits=kv_bits, hd=HD, plan=plan)
+    want = _dense_reference(q, logical, valid_len, qpos, kv_bits, HD)
+    return paged, ring, want
+
+
+@pytest.mark.parametrize("case", ["partly_live", "dead_steps", "empty_row",
+                                  "ragged_table", "scrambled"])
+@pytest.mark.parametrize("kv_bits", [0, 8, 4, 2])
+def test_paged_walk_matches_reference_and_ring(case, kv_bits):
+    """The paged kernel copies each step's live table entries and groups
+    the online softmax by ``chunks`` pages: it agrees with the dense
+    reference, and bit for bit with the contiguous read of the same
+    logical view (the same ``block_k`` groups, the same shared math)."""
+    pool, bt, valid_len, chunks = _walk_case(case, kv_bits)
+    paged, ring, want = _paged_and_contiguous(pool, bt, valid_len, chunks,
+                                              kv_bits)
+    np.testing.assert_allclose(np.asarray(paged), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(paged), np.asarray(ring))
+    if case == "empty_row":
+        np.testing.assert_array_equal(np.asarray(paged[1]), 0.0)
+
+
+@pytest.mark.parametrize("kv_bits", [4, 2])
+def test_paged_walk_row_does_not_depend_on_batch_mates(kv_bits):
+    """A row reads the same bits alone as beside other rows, whose pages
+    the walk prefetches across the row boundary."""
+    pool, bt, valid_len, chunks = _walk_case("empty_row", kv_bits)
+    q = np.asarray(jax.random.normal(jax.random.PRNGKey(2),
+                                     (bt.shape[0], 1, H, HD)))
+    batch = ulppack_attention.fused_decode_attention(
+        jnp.asarray(q), _lane_flat(pool), valid_len, valid_len[:, None] - 1,
+        kv_bits=kv_bits, hd=HD, plan=_walk_plan(chunks), block_tables=bt)
+    for r in range(bt.shape[0]):
+        alone = ulppack_attention.fused_decode_attention(
+            jnp.asarray(q[r:r + 1]), _lane_flat(pool), valid_len[r:r + 1],
+            valid_len[r:r + 1, None] - 1, kv_bits=kv_bits, hd=HD,
+            plan=_walk_plan(chunks), block_tables=bt[r:r + 1])
+        np.testing.assert_array_equal(np.asarray(alone[0]),
+                                      np.asarray(batch[r]))
+
+
+@pytest.mark.parametrize("kv_bits", [8, 4, 2])
+def test_paged_walk_reads_only_live_rows(kv_bits):
+    """Poison every pool page that no live table entry names, and the dead
+    rows of the live pages, with NaN scales and all-ones words: the output
+    is finite and equal bit for bit to the unpoisoned run."""
+    pool, bt, valid_len, chunks = _walk_case("scrambled", kv_bits)
+    clean, _, _ = _paged_and_contiguous(pool, bt, valid_len, chunks, kv_bits)
+    live = np.zeros(pool["k"].shape[:2], bool)      # [P, PS] rows read
+    for row, n in zip(np.asarray(bt), np.asarray(valid_len)):
+        for t in range(int(n)):
+            live[row[t // PS], t % PS] = True
+    dead = jnp.asarray(~live)
+    poisoned = {
+        kk: jnp.where(dead.reshape(*dead.shape, *([1] * (vv.ndim - 2))),
+                      jnp.asarray(jnp.nan if kk.endswith("scale") else -1,
+                                  vv.dtype), vv)
+        for kk, vv in pool.items()}
+    got, _, _ = _paged_and_contiguous(poisoned, bt, valid_len, chunks,
+                                      kv_bits)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+
+
+# ---------------------------------------------------------------------------
 # Planner + autotuner plumbing
 # ---------------------------------------------------------------------------
 

@@ -463,3 +463,25 @@ def test_lane_flat_pool_stores_the_ring_words(kv_bits):
     tokens = [{r.uid: tuple(r.output) for r in eng.run_to_completion()}
               for eng in (ring, pool)]
     assert tokens[0] == tokens[1] and len(tokens[0]) == 2
+
+
+def test_decode_page_counters(tiny):
+    """Metrics.decode_pages_live counts each decode launch's live table
+    entries per live row, decode_pages_table its rows x table entries.
+    Two requests served one after the other, pages of 16, 4 entries per
+    row of 2: a 20-token prompt with 4 tokens out decodes at lengths 21,
+    22, 23 (2 pages each), a 33-token prompt with 3 out at 34, 35 (3
+    pages each)."""
+    cfg, params = tiny
+    eng = ServingEngine(cfg, params, config=EngineConfig(
+        max_batch=2, max_len=64, packed=False, prefill_chunk=16,
+        paged=True, page_size=16, prefix_sharing=False))
+    rng = np.random.default_rng(5)
+    for uid, (n, new) in enumerate([(20, 4), (33, 3)]):
+        assert eng.submit(Request(uid=uid, prompt=rng.integers(
+            0, cfg.vocab_size, n).astype(np.int32), max_new_tokens=new))
+        eng.run_to_completion()
+    m = eng.metrics
+    assert m.decode_pages_live == 3 * 2 + 2 * 3
+    assert m.decode_pages_table == 5 * 2 * 4
+    assert m.report()["decode_live_page_share"] == round(12 / 40, 4)

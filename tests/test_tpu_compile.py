@@ -125,10 +125,11 @@ def test_paged_decode_writes_the_pool_in_place(one_chip, kv_bits):
     of 16 rows, 16 rows in the batch, 64 table entries), caches donated:
     the paged write then the attention kernel.  The word leaves enter in
     the row-major layout that the scatter and the kernel both read, no
-    synchronous copy of a whole word leaf is left (a 4-D
-    [P, ps, KVH, words] pool took three per leaf: into the scatter's
-    layout, back, and into the kernel's flat view), and every pool leaf
-    is aliased to its output."""
+    copy of a whole word leaf is left, synchronous or staged ahead into
+    VMEM (a 4-D [P, ps, KVH, words] pool took three per leaf: into the
+    scatter's layout, back, and into the kernel's flat view; the kernel
+    reads only live pages of a pool kept in HBM), and every pool leaf is
+    aliased to its output."""
     b, page, n_pages, pool = 16, 16, 64, 1536
     cfg = CFG.replace(quant=dataclasses.replace(CFG.quant, kv_bits=kv_bits))
 
@@ -166,7 +167,8 @@ def test_paged_decode_writes_the_pool_in_place(one_chip, kv_bits):
     assert len(layouts) == 2, entry
     assert all(lay.startswith("{2,1,0") for lay in layouts), layouts
     copies = [ln for ln in txt.splitlines()
-              if re.search(r"= s32\[[\d,]+\]\{[^}]*\} copy\(", ln)
+              if re.search(r"= \(?s32\[[\d,]+\]\{[^}]*\}.* copy(-start)?\(",
+                           ln)
               and math.prod(map(int, word.search(ln)[1].split(",")))
               == math.prod(leaf)]
     assert not copies, copies[:3]
@@ -175,3 +177,39 @@ def test_paged_decode_writes_the_pool_in_place(one_chip, kv_bits):
     aliased = {int(p) for p in re.findall(
         r"\{\d*\}: \((\d+), \{\}", head)}
     assert {3, 4, 5, 6} <= aliased, head[:400]
+
+
+def test_paged_decode_kernel_size_does_not_grow_with_chunks(one_chip):
+    """The paged kernel copies a step's pages in a loop, so its Mosaic
+    module is about the same size at 2 and at 8 pages per grid step: a
+    body unrolled over pages would grow every layer's lowering, in every
+    process that serves."""
+    b, page, n_pages = 16, 16, 96
+    words = HD // 8                                    # 4-bit KV
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    cache = {"k": sds((1536, page, KVH * words), jnp.int32),
+             "v": sds((1536, page, KVH * words), jnp.int32),
+             "k_scale": sds((1536, page, KVH), jnp.bfloat16),
+             "v_scale": sds((1536, page, KVH), jnp.bfloat16)}
+
+    def body_bytes(chunks):
+        plan = plan_lib.KernelPlan(
+            op="attention_decode", backend="pallas", interpret=False,
+            block_k=chunks * page, chunks=chunks)
+
+        def attend(q, cache, valid, qpos, bt):
+            return ulppack_attention.fused_decode_attention(
+                q, cache, valid, qpos, kv_bits=4, hd=HD, plan=plan,
+                block_tables=bt)
+
+        txt = jax.jit(attend).lower(
+            sds((b, 1, H, HD), jnp.bfloat16), cache, sds((b,), jnp.int32),
+            sds((b, 1), jnp.int32), sds((b, n_pages), jnp.int32)).as_text()
+        body, = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', txt)
+        return len(body)
+
+    small, large = body_bytes(2), body_bytes(8)
+    assert large < 1.25 * small, (small, large)

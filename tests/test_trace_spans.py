@@ -139,6 +139,16 @@ def test_one_cow_span_per_copied_page(plain):
         assert s[3]["src"] != s[3]["dst"]
 
 
+def test_decode_pass_carries_its_page_counts(plain):
+    """Each decode launch's span names its live table entries and the
+    whole table's: 4 rows of 3 entries; three 20-token prompts decode at
+    lengths 21-23, two pages each, nine row launches in all."""
+    spans, _ = plain
+    decode = [s[3] for s in spans if s[0] == "engine.decode_pass"]
+    assert decode and all(d["pages_table"] == 4 * 3 for d in decode)
+    assert sum(d["pages_live"] for d in decode) == 9 * 2
+
+
 def test_speculative_pass_spans(speculative):
     spans, _ = speculative
     names = {s[0] for s in spans}
